@@ -25,6 +25,9 @@ def main() -> None:
     suites = args.suite or list(SUITES)
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_histogram,
         bench_kmeans,

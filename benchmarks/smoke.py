@@ -151,6 +151,9 @@ def main() -> None:
                     help="subset of suites (default: all)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_histogram,
         bench_kmeans,

@@ -83,6 +83,7 @@ import numpy as np
 from repro.api import shm
 from repro.api.autotune import should_fold_remote, should_steal
 from repro.api.chunkstore import ChunkHandle, StoreManifest, chunk_stores, resolve_chunk
+from repro.api.cluster_worker import WORKER_PLATFORM
 from repro.api.shm import ShmBlockRef, ShmStore, shm_available
 from repro.api.executors import (
     _LIVE_POOLS,
@@ -598,9 +599,12 @@ class ClusterExecutor(_PlanExecutor):
         # out_of_core: lowering attaches chunk_refs, so the parent pins a
         # unit's chunks for the whole remote round-trip (and releases them
         # on completion OR requeue — the fault-path contract tests assert).
+        # prefer_pallas follows the WORKERS' platform, not the driver's: a
+        # TPU driver must not ship kernels that its CPU workers interpret.
         return dataclasses.replace(
             super().capabilities,
             name=type(self).__name__,
+            prefer_pallas=WORKER_PLATFORM == "tpu",
             remote=True,
             out_of_core=True,
             exporter=self._export_block if self._shm is not None else None,

@@ -58,6 +58,12 @@ same host), so a replayed unit — or the same unit on a different worker —
 produces bit-identical partials.  That is the Chunks-and-Tasks replay
 story: fault tolerance is "run the pure task descriptor again".
 
+Platform: a worker's JAX is pinned to the CPU (:data:`WORKER_PLATFORM`)
+before its first JAX call.  An accelerator belongs to the one process that
+drives it — the driver — and a worker that reached for it would either fail
+on the runtime's lock or, with no platform set, quietly fall back to the
+CPU anyway.
+
 Fault injection (tests / the CI fault lane): ``kill_after`` makes the
 worker ``os._exit`` on *receiving* its nth dispatch (the unit is lost
 in-flight, exercising requeue); ``kill_on_retry`` does the same when it
@@ -80,6 +86,9 @@ import time
 import traceback
 
 __all__ = ["worker_main"]
+
+#: the JAX platform every worker runs on (the driver keeps the accelerator)
+WORKER_PLATFORM = "cpu"
 
 #: exit codes used by injected faults (visible in worker logs / waitpid)
 KILLED_EXIT = 23
@@ -187,6 +196,9 @@ def worker_main(
     result_min_bytes: int = 1024,
 ) -> None:
     """Entry point of one cluster worker process."""
+    import jax
+
+    jax.config.update("jax_platforms", WORKER_PLATFORM)
     log = open(log_path, "a") if log_path else None
     _log_line(log, worker_id, f"start pid={os.getpid()} location={location}")
 

@@ -6,7 +6,7 @@ location group execute as ONE sharded dispatch.  Where LocalExecutor emits
 one host dispatch per task and ThreadedExecutor overlaps them with
 threads, MeshExecutor stacks the same-signature tasks of a lowered
 :class:`~repro.api.lowering.TaskGraph` along a leading axis, shards that
-axis over the mesh with :func:`repro.distributed.compat.shard_map`, folds
+axis over the mesh with :func:`jax.shard_map`, folds
 each rank's local tasks with the plan's combine, and merges across ranks
 with a psum-style collective (all-gather + fold, the all-reduce of an
 arbitrary associative monoid — plain ``psum`` when the combine is a sum).
@@ -43,7 +43,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api.executors import _PlanExecutor, _Unit
 from repro.api.lowering import (
@@ -54,7 +54,6 @@ from repro.api.lowering import (
     stacked_fold,
 )
 from repro.core.engine import TaskEngine
-from repro.distributed.compat import shard_map
 
 __all__ = ["MeshExecutor"]
 
@@ -172,13 +171,18 @@ class MeshExecutor(_PlanExecutor):
         mesh = self._mesh(m)
         axis = self.axis_name
 
-        # stack each per-task data operand along a new leading (group) axis;
-        # extras are plan-wide and shared by every task of the signature
+        # stack each per-task data operand along a new leading (group) axis
+        # and place it split over the mesh; extras are plan-wide, shared by
+        # every task of the signature, and replicated
         per_task = [t.operands() for t in tasks]
         stacked = tuple(
-            jnp.stack([ops[j] for ops in per_task], axis=0) for j in range(n_data)
+            jax.device_put(
+                jnp.stack([ops[j] for ops in per_task], axis=0),
+                NamedSharding(mesh, P(axis)),
+            )
+            for j in range(n_data)
         )
-        extras = per_task[0][n_data:]
+        extras = jax.device_put(per_task[0][n_data:], NamedSharding(mesh, P()))
         del per_task
 
         # local fold over the rank's tasks = the generic partition body over
@@ -196,7 +200,7 @@ class MeshExecutor(_PlanExecutor):
             )
             return stacked_fold(combine)(gathered)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             fused,
             mesh=mesh,
             in_specs=(P(axis),) * n_data + (P(),) * len(extras),

@@ -35,9 +35,10 @@ __all__ = ["cascade_svm", "svc_train", "CascadeSVMResult"]
 
 
 def _rbf(a: jax.Array, b: jax.Array, gamma: float) -> jax.Array:
+    # full float32 cross term (a TPU's default rounds matmul inputs to bf16)
     d2 = (
         jnp.sum(a * a, 1)[:, None]
-        - 2.0 * a @ b.T
+        - 2.0 * jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(b * b, 1)[None, :]
     )
     return jnp.exp(-gamma * d2)

@@ -29,7 +29,12 @@ from repro.api import Collection, Executor, ExecutionPolicy, SplIter, as_policy
 from repro.api.kernels import PartitionKernel, pallas_interpret, register_partition_kernel
 from repro.core.blocked import BlockedArray
 from repro.core.engine import EngineReport
-from repro.kernels.partition_reduce import partition_histogramdd
+from repro.kernels.partition_reduce import (
+    EXACT_COUNT_ROWS,
+    histdd_vmem,
+    partition_histogramdd,
+    row_tile,
+)
 
 __all__ = ["histogram", "histogramdd_block"]
 
@@ -57,9 +62,14 @@ def _histogram_kernel_factory(args: tuple, kwargs: dict) -> PartitionKernel | No
     bins, lo, hi = kwargs["bins"], kwargs["lo"], kwargs["hi"]
 
     def supports(stacked_shape: tuple, extra_args: tuple) -> bool:
-        # flat one-hot grid: keep the VMEM accumulator (bins**d cells) sane
-        d = stacked_shape[-1]
-        return not extra_args and bins**d <= 1 << 20
+        # admit what compiles: the (bins**d)-cell accumulator plus a row tile
+        # must fit the kernel's VMEM budget, and f32 counts must stay exact
+        nb, rows, d = stacked_shape
+        return (
+            not extra_args
+            and nb * rows < EXACT_COUNT_ROWS
+            and row_tile(rows, *histdd_vmem(bins, d)) is not None
+        )
 
     return PartitionKernel(
         name="partition_histogramdd",

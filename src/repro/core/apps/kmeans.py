@@ -31,25 +31,29 @@ from repro.api.executors import _default_local
 from repro.api.kernels import PartitionKernel, pallas_interpret, register_partition_kernel
 from repro.core.blocked import BlockedArray
 from repro.core.engine import EngineReport
-from repro.kernels.partition_reduce import partition_kmeans
+from repro.kernels.partition_reduce import kmeans_vmem, partition_kmeans, row_tile
 
 __all__ = ["kmeans", "partial_sum_block", "KMeansResult"]
+
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def partial_sum_block(block: jax.Array, centers: jax.Array):
     """One Lloyd E+partial-M step on a ``(rows, d)`` block.
 
     Returns ``(sums (k,d), counts (k,))`` — the associative partial state.
+    Matmuls run at full float32 precision (a TPU's default rounds their
+    inputs to bfloat16), as the fused kernel does.
     """
     d2 = (
         jnp.sum(block * block, axis=1)[:, None]
-        - 2.0 * block @ centers.T
+        - 2.0 * jnp.matmul(block, centers.T, precision=_F32)
         + jnp.sum(centers * centers, axis=1)[None, :]
     )                                                        # (rows, k)
     assign = jnp.argmin(d2, axis=1)                          # (rows,)
     k = centers.shape[0]
     one_hot = jax.nn.one_hot(assign, k, dtype=block.dtype)   # (rows, k)
-    sums = one_hot.T @ block                                 # (k, d)
+    sums = jnp.matmul(one_hot.T, block, precision=_F32)      # (k, d)
     counts = jnp.sum(one_hot, axis=0)                        # (k,)
     return sums, counts
 
@@ -74,8 +78,18 @@ def _kmeans_kernel_factory(args: tuple, kwargs: dict) -> PartitionKernel | None:
         fn=lambda stacked, centers: partition_kmeans(
             stacked, centers, interpret=pallas_interpret()
         ),
-        supports=lambda stacked_shape, extra_args: len(extra_args) == 1,
+        supports=_kmeans_supports,
     )
+
+
+def _kmeans_supports(stacked_shape: tuple, extra_args: tuple) -> bool:
+    """One centers operand, and a row tile that fits the kernel's VMEM."""
+    if len(extra_args) != 1:
+        return False
+    _, rows, d = stacked_shape
+    # a pipelined loop's centers are a Deferred whose k is not known yet
+    k = getattr(extra_args[0], "shape", (1,))[0]
+    return row_tile(rows, kmeans_vmem(d, k)) is not None
 
 
 register_partition_kernel(partial_sum_block, _kmeans_kernel_factory)

@@ -46,10 +46,14 @@ class KNNResult:
 
 
 def _lookup(fit_x: jax.Array, fit_ids: jax.Array, q: jax.Array, k: int):
-    """Distances of ``q`` against one structure → per-query top-k (d², id)."""
+    """Distances of ``q`` against one structure → per-query top-k (d², id).
+
+    The cross term runs at full float32 precision: at a TPU's default
+    (bfloat16 inputs) its error would swamp near-neighbor distances.
+    """
     d2 = (
         jnp.sum(q * q, 1)[:, None]
-        - 2.0 * q @ fit_x.T
+        - 2.0 * jnp.matmul(q, fit_x.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(fit_x * fit_x, 1)[None, :]
     )
     neg, pos = jax.lax.top_k(-d2, k)          # smallest distances

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
 from repro.optim.compression import int8_compress
 
 __all__ = [
@@ -35,7 +34,7 @@ def hierarchical_psum(x: jax.Array, *, fast_axis: str, slow_axis: str) -> jax.Ar
     """Two-level all-reduce for use INSIDE shard_map: RS(fast) → AR(slow) →
     AG(fast).  Equivalent to ``psum(x, (fast, slow))`` with 2/W of the flat
     schedule's slow-link bytes (W = fast-axis size)."""
-    w = axis_size(fast_axis)
+    w = jax.lax.axis_size(fast_axis)
     n = x.shape[0]
     if n % w:  # ragged leading dim: fall back to the flat schedule
         return jax.lax.psum(x, (fast_axis, slow_axis))
@@ -65,7 +64,7 @@ def psum_pod_hierarchical(tree: Any, mesh: Mesh) -> Any:
         )
 
     specs = jax.tree.map(lambda _: P(), tree)
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(specs,),
@@ -84,7 +83,7 @@ def compressed_psum_pod(x: jax.Array, *, fast_axis: str, slow_axis: str) -> jax.
     its own scale; pair with error feedback in the optimizer for the
     quantization residual).
     """
-    w = axis_size(fast_axis)
+    w = jax.lax.axis_size(fast_axis)
     n = x.shape[0]
     if n % w:
         return jax.lax.psum(x, (fast_axis, slow_axis))

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 __all__ = ["gpipe"]
 
@@ -49,7 +48,7 @@ def gpipe(
     p_specs = jax.tree.map(lambda _: P(axis), stage_params)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(p_specs, P(None)),  # every rank sees the full block stream
         out_specs=P(None),

@@ -15,25 +15,14 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer jax;
-    on older releases every axis is implicitly Auto, which is exactly what
-    we request, so the kwarg is passed only when the enum exists.
-    """
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_test_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
-    """Small mesh for subprocess-isolated distribution tests."""
-    return compat_make_mesh(shape, axes)
+    """A mesh of ``shape`` over the first devices, every axis Auto-sharded."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

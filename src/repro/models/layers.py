@@ -153,9 +153,7 @@ def _cache_write_sharded(cache, new, pos, rules):
         row = jnp.where(in_range, n.astype(c.dtype), row)
         return jax.lax.dynamic_update_slice_in_dim(c, row, li, axis=1)
 
-    from repro.distributed.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(c_spec, n_spec, P()),

@@ -12,9 +12,9 @@ import numpy as np
 
 
 def _mesh(shape, axes):
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_test_mesh
 
-    return compat_make_mesh(shape, axes)
+    return make_test_mesh(shape, axes)
 
 
 def check_hierarchical_psum() -> None:
@@ -22,7 +22,6 @@ def check_hierarchical_psum() -> None:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
     from repro.distributed.collectives import hierarchical_psum
 
     mesh = _mesh((2, 4), ("pod", "data"))
@@ -34,7 +33,7 @@ def check_hierarchical_psum() -> None:
     def hier(v):
         return hierarchical_psum(v, fast_axis="data", slow_axis="pod")
 
-    sm = lambda f: shard_map(
+    sm = lambda f: jax.shard_map(
         f, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False
     )
     got = sm(hier)(x)
@@ -49,13 +48,12 @@ def check_compressed_psum() -> None:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
     from repro.distributed.collectives import compressed_psum_pod
 
     mesh = _mesh((2, 4), ("pod", "data"))
     x = jnp.asarray(np.random.default_rng(1).standard_normal((16, 32)), jnp.float32)
 
-    got = shard_map(
+    got = jax.shard_map(
         lambda v: compressed_psum_pod(v, fast_axis="data", slow_axis="pod"),
         mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
     )(x)
@@ -271,6 +269,9 @@ def check_mesh_executor() -> None:
         assert rm.dispatches == 1, (fusion, rm.dispatches)
         assert rm.bytes_moved > 0, fusion        # collective traffic estimate
         assert rm.merges >= 1, fusion
+        # the one dispatch spanned the whole mesh: its merged value is
+        # replicated over all 8 devices, not left on the first
+        assert len(hm.sharding.device_set) == 8, (fusion, hm.sharding)
 
     rb = kmeans(ba, k=4, iters=3, policy=Baseline())
     rm_ = kmeans(

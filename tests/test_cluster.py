@@ -22,6 +22,7 @@ from __future__ import annotations
 import os
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -511,6 +512,52 @@ def test_cluster_executor_satisfies_protocol():
     ex = ClusterExecutor()
     assert isinstance(ex, Executor)
     ex.close()
+
+
+def _cpu_marker(x):
+    """1 + x when the jit that traces this runs on the CPU backend."""
+    return x + (1.0 if jax.default_backend() == "cpu" else 0.0)
+
+
+def test_worker_jax_is_pinned_to_cpu(monkeypatch):
+    """A worker started with a platform it must not open still runs on the
+    CPU: the pin comes before the worker's first JAX call."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # inherited by spawned workers
+    ex = _cluster()
+    try:
+        out = ex.task(_cpu_marker, key="cpu-probe")(np.zeros((), np.float32))
+        assert ex.report.remote_dispatches == 1  # it ran on a worker
+    finally:
+        ex.close()
+    assert float(out) == 1.0
+
+
+def test_prefer_pallas_follows_workers_not_driver(monkeypatch, points):
+    """On a TPU driver, fusion="auto" keeps the scan for CPU workers, while
+    an explicit fusion="pallas" still ships the kernel (interpreted there)."""
+    from functools import partial
+
+    from repro.core.apps.histogram import histogramdd_block
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ex = _cluster()
+    try:
+        assert LocalExecutor().capabilities.prefer_pallas  # the driver's view
+        assert not ex.capabilities.prefer_pallas
+
+        def kinds(fusion):
+            plan = (
+                Collection.from_blocked(points)
+                .split(SplIter(partitions_per_location=2, fusion=fusion))
+                .map_blocks(partial(histogramdd_block, bins=8, lo=0.0, hi=1.0))
+                .reduce(lambda a, b: a + b)
+            )
+            return {t.kind for t in ex.lower(plan.plan()).tasks}
+
+        assert kinds("auto") == {"partition_scan"}
+        assert kinds("pallas") == {"partition_pallas"}
+    finally:
+        ex.close()
 
 
 # ---------------------------------------------------------------------------

@@ -14,9 +14,9 @@ from repro.models import build_model
 
 
 def _mesh11():
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_test_mesh
 
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_test_mesh((1, 1))
 
 
 @pytest.mark.parametrize("arch", ["qwen3-32b", "mixtral-8x7b", "qwen2-72b"])

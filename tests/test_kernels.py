@@ -17,7 +17,11 @@ except ImportError:  # pragma: no cover
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.partition_reduce import partition_histogram, partition_kmeans
+from repro.kernels.partition_reduce import (
+    MAX_TILE_ROWS,
+    partition_histogram,
+    partition_kmeans,
+)
 from repro.kernels.ssd_scan import ssd_scan
 
 RNG = np.random.default_rng(0)
@@ -154,6 +158,73 @@ class TestPartitionReduce:
                 np.asarray(s), np.asarray(outs[0][0]), rtol=1e-5, atol=1e-5
             )
             np.testing.assert_array_equal(np.asarray(c), np.asarray(outs[0][1]))
+
+    # rows past MAX_TILE_ROWS walk the block in tiles: 8,192 + 300 leaves a
+    # masked 300-row tail, 2 x 8,192 splits evenly
+    @pytest.mark.parametrize("nb,rows,d,k", [
+        (2, MAX_TILE_ROWS + 300, 20, 8), (1, 2 * MAX_TILE_ROWS, 5, 3),
+    ])
+    def test_kmeans_row_tiles_mask_the_tail(self, nb, rows, d, k):
+        """No row of a partial last tile's padding reaches a sum or a count."""
+        st_ = randn(nb, rows, d)
+        cen = randn(k, d)
+        sums, counts = partition_kmeans(st_, cen)
+        rs, rc = ref.kmeans_ref(st_, cen)
+        np.testing.assert_allclose(np.asarray(sums), np.asarray(rs), rtol=1e-5, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(counts), np.asarray(rc))
+
+    @pytest.mark.parametrize("nb,rows,d", [
+        (2, MAX_TILE_ROWS + 300, 3), (1, 2 * MAX_TILE_ROWS, 5),
+    ])
+    def test_histogramdd_row_tiles_mask_the_tail(self, nb, rows, d):
+        from repro.core.apps.histogram import histogramdd_block
+        from repro.kernels.partition_reduce import partition_histogramdd
+
+        st_ = jnp.asarray(RNG.normal(0.5, 0.7, (nb, rows, d)).astype(np.float32))
+        h = partition_histogramdd(st_, bins=8, lo=0.0, hi=1.0)
+        want = sum(
+            histogramdd_block(st_[i], bins=8, lo=0.0, hi=1.0) for i in range(nb)
+        )
+        np.testing.assert_array_equal(np.asarray(h), np.asarray(want))
+
+    def test_histogram_row_tiles_mask_the_tail(self):
+        st_ = jnp.asarray(
+            RNG.normal(0.5, 0.7, (2, MAX_TILE_ROWS + 300, 3)).astype(np.float32)
+        )
+        h = partition_histogram(st_, bins=8, lo=0.0, hi=1.0)
+        r = ref.histogram_ref(st_, bins=8, lo=0.0, hi=1.0)
+        np.testing.assert_array_equal(np.asarray(h), np.asarray(r))
+
+    def test_row_tile_is_bounded_by_vmem_not_block_rows(self):
+        from repro.kernels.partition_reduce import (
+            VMEM_BUDGET_BYTES,
+            kmeans_vmem,
+            row_tile,
+        )
+
+        per_row = kmeans_vmem(20, 8)
+        assert row_tile(1000, per_row) == 1000      # a small block is one tile
+        for rows in (204800, 1 << 22):
+            tile = row_tile(rows, per_row)
+            assert tile % 128 == 0 and tile * per_row <= VMEM_BUDGET_BYTES
+        assert row_tile(204800, VMEM_BUDGET_BYTES) is None  # not even 128 rows
+
+    @pytest.mark.parametrize("d,nb,rows,admitted", [
+        (5, 16, 32768, True),     # 32,768 cells
+        (6, 4, 4096, True),       # 262,144 cells
+        (7, 4, 4096, False),      # 2**21 cells: the accumulator alone overflows
+        (3, 1024, 1 << 14, False),  # 2**24 rows: f32 counts no longer exact
+    ])
+    def test_histogram_guard_reckons_vmem_bytes(self, d, nb, rows, admitted):
+        import functools
+
+        from repro.api.kernels import partition_kernel_for
+        from repro.core.apps.histogram import histogramdd_block
+
+        kernel = partition_kernel_for(
+            functools.partial(histogramdd_block, bins=8, lo=0.0, hi=1.0)
+        )
+        assert kernel.supported((nb, rows, d), ()) is admitted
 
 
 class TestSSDScan:

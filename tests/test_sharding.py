@@ -24,9 +24,9 @@ from repro.models import build_model
 def mesh():
     # 1 real device: a (1, 1) mesh — axis *names* drive pspec construction,
     # extent-1 axes make every dim "divisible" so rules resolve fully.
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_test_mesh
 
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_test_mesh((1, 1))
 
 
 def test_param_pspec_stacked_by_rank(mesh):
@@ -56,9 +56,9 @@ def test_param_pspec_fsdp_disable(mesh):
 
 
 def test_param_pspec_nondivisible_replicates():
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_test_mesh
 
-    mesh2 = compat_make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_test_mesh((1, 1))
     # simulate extent via a fake mesh is moot at extent 1; use rank mismatch:
     # a rank the rules don't expect must fully replicate, never crash
     assert param_pspec("/seg0/0/mixer/wq", (3, 4, 64, 8, 16), mesh2) == P(
