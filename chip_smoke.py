@@ -33,9 +33,9 @@ only, so a quiet fall-back to the scan fails the run.  ``--chips 4`` runs
 ``device`` and the kmeans data through ``engine("mesh")`` against
 ``engine("local")``, and checks that the dispatch spanned four devices.
 
-Timing lines are information, not metrics.  The last line of standard
-output is the verdict: ``{"ok": true, "device": {...}}``.  Any failed
-phase raises, so the exit code is not 0 and no verdict is printed.
+The last line of standard output is the verdict: ``{"ok": true,
+"device": {...}}``.  Any failed phase raises, so the exit code is not 0
+and no verdict is printed.
 """
 
 from __future__ import annotations
@@ -47,7 +47,6 @@ import json
 import math
 import os
 import sys
-import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -159,32 +158,10 @@ def _assert_lowered_to(ex, collection: Collection, kind: str, label: str) -> Non
         raise AssertionError(f"{label}: lowered to {sorted(kinds)}, expected only {kind}")
 
 
-class Timings:
-    """First-run and steady seconds per phase (information only)."""
-
-    def __init__(self, phase: str):
-        self.phase, self.first, self.steady = phase, 0.0, 0.0
-
-    def add(self, label: str, first_s: float, steady_s: float) -> None:
-        self.first += first_s
-        self.steady += steady_s
-        print(f"  {self.phase} {label}: first_s={first_s:.4f} steady_s={steady_s:.4f}",
-              flush=True)
-
-    def done(self) -> None:
-        compile_s = max(self.first - self.steady, 0.0)
-        print(f"timing {self.phase}: compile_s={compile_s:.4f} steady_s={self.steady:.4f}",
-              flush=True)
-
-
 def _twice(fn):
-    """``fn()`` run twice: (second result, first seconds, second seconds)."""
-    walls = []
-    for _ in range(2):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn())
-        walls.append(time.perf_counter() - t0)
-    return out, walls[0], walls[1]
+    """``fn()`` run twice, the second time from the jit cache: its result."""
+    jax.block_until_ready(fn())
+    return jax.block_until_ready(fn())
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +215,6 @@ def _kmeans_reference(x, s: Sizes, seed: int) -> np.ndarray:
 
 
 def phase_kmeans(s: Sizes, seed: int, chips: int) -> None:
-    t = Timings("kmeans")
     n_blocks = s.locs * s.km_blocks
     x = _uniform(_data_key(seed, "kmeans"), (n_blocks * s.km_rows, s.km_d))
     want = _kmeans_reference(x, s, seed)
@@ -268,8 +244,6 @@ def phase_kmeans(s: Sizes, seed: int, chips: int) -> None:
                 _assert_lowered_to(ex, plan, "partition_pallas", f"kmeans {label}")
             res = kmeans(ba, k=s.km_k, iters=s.km_iters, seed=seed, policy=pol, executor=ex)
             centers = jax.block_until_ready(res.centers)
-        walls = [r.wall_s for r in res.reports]
-        t.add(label, walls[0], float(np.mean(walls[1:])))
         err = float(np.abs(np.asarray(centers) - want).max())
         print(f"  kmeans {label}: max |centers - reference| = {err:.3g}", flush=True)
         np.testing.assert_allclose(np.asarray(centers), want, rtol=0, atol=KMEANS_ATOL,
@@ -291,7 +265,6 @@ def phase_kmeans(s: Sizes, seed: int, chips: int) -> None:
                                    rtol=0, atol=KMEANS_ATOL, err_msg="mesh vs local")
         print(f"  kmeans mesh: {spanned} devices, {moved.pop()} bytes merged per iteration",
               flush=True)
-    t.done()
 
 
 @functools.partial(jax.jit, static_argnames=("bins",))
@@ -305,7 +278,6 @@ def _histogram_reference(x, bins):
 
 
 def phase_histogram(s: Sizes, seed: int) -> None:
-    t = Timings("histogram")
     n_blocks = s.locs * s.hist_blocks
     x = _uniform(_data_key(seed, "histogram"), (n_blocks * s.hist_rows, s.hist_d))
     want = np.asarray(_histogram_reference(x, s.bins))
@@ -320,13 +292,11 @@ def phase_histogram(s: Sizes, seed: int) -> None:
             if getattr(pol, "fusion", None) == "pallas":
                 plan = Collection.from_blocked(ba).split(pol).map_blocks(block_fn).reduce(_add)
                 _assert_lowered_to(ex, plan, "partition_pallas", f"histogram {label}")
-            (h, _), first, steady = _twice(
+            h, _ = _twice(
                 lambda: histogram(ba, bins=s.bins, lo=0.0, hi=1.0, policy=pol, executor=ex)
             )
-        t.add(label, first, steady)
         np.testing.assert_array_equal(np.asarray(h).reshape(-1), want,
                                       err_msg=f"histogram {label} vs scatter-add reference")
-    t.done()
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -354,7 +324,6 @@ def _check_knn(res, fit: np.ndarray, q: np.ndarray, want_d, want_i, label: str) 
 
 
 def phase_knn_svm(s: Sizes, seed: int) -> None:
-    t = Timings("knn_svm")
     kf, kq, kx, kw, kn = jax.random.split(_data_key(seed, "knn_svm"), 5)
 
     n_fit = s.locs * s.knn_blocks
@@ -366,9 +335,7 @@ def phase_knn_svm(s: Sizes, seed: int) -> None:
     fit_np, q_np = np.asarray(fit, np.float64), np.asarray(q)
     for pol in (Baseline(), SplIter()):
         with engine("local") as ex:
-            res, first, steady = _twice(lambda: knn(fit_b, q_b, k=s.knn_k, policy=pol,
-                                                    executor=ex))
-        t.add(f"knn/local/{pol.mode_name}", first, steady)
+            res = _twice(lambda: knn(fit_b, q_b, k=s.knn_k, policy=pol, executor=ex))
         _check_knn(res, fit_np, q_np, want_d, want_i, f"local/{pol.mode_name}")
 
     # one 4,096-row block per location (the bench's balanced layout): both
@@ -383,10 +350,9 @@ def phase_knn_svm(s: Sizes, seed: int) -> None:
     models = {}
     for pol in (Baseline(), SplIter()):
         with engine("local") as ex:
-            res, first, steady = _twice(lambda: cascade_svm(
+            res = _twice(lambda: cascade_svm(
                 xb, yb, num_sv=s.svm_num_sv, steps=s.svm_steps, iterations=1,
                 policy=pol, executor=ex))
-        t.add(f"svm/local/{pol.mode_name}", first, steady)
         svs = zip(np.asarray(res.sv_x), np.asarray(res.sv_y))
         if not all(tuple(r) + (float(v),) in rows for r, v in svs):
             raise AssertionError(f"svm {pol.mode_name}: a support vector is not a labelled row")
@@ -394,11 +360,9 @@ def phase_knn_svm(s: Sizes, seed: int) -> None:
     (name_a, model_a), (name_b, model_b) = models.items()
     for part, a, b in zip(("sv_x", "sv_y", "sv_alpha"), model_a, model_b):
         np.testing.assert_array_equal(a, b, err_msg=f"svm {part}: {name_a} vs {name_b}")
-    t.done()
 
 
 def phase_cluster(s: Sizes, seed: int) -> None:
-    t = Timings("cluster")
     cpu = jax.devices("cpu")[0]
     n_blocks = s.cl_locs * s.cl_blocks
     rng = np.random.default_rng(seed)
@@ -422,11 +386,8 @@ def phase_cluster(s: Sizes, seed: int) -> None:
                          executor=ex)
             if sum(r.remote_dispatches for r in res.reports) == 0:
                 raise AssertionError("cluster kmeans dispatched nothing to its workers")
-    walls = [r.wall_s for r in res.reports]
-    t.add("cluster/spliter/auto", walls[0], float(np.mean(walls[1:])))
     np.testing.assert_array_equal(np.asarray(res.centers), np.asarray(want),
                                   err_msg="cluster kmeans vs local kmeans on the CPU")
-    t.done()
 
 
 def main() -> None:
@@ -445,14 +406,12 @@ def main() -> None:
     enable_compile_cache()
     sizes = TINY if args.tiny else FULL
 
-    t0 = time.perf_counter()
     device = phase_device(args.chips, args.tiny)
     phase_kmeans(sizes, args.seed, args.chips)
     if args.chips == 1:
         phase_histogram(sizes, args.seed)
         phase_knn_svm(sizes, args.seed)
         phase_cluster(sizes, args.seed)
-    print(f"timing total: wall_s={time.perf_counter() - t0:.4f}", flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

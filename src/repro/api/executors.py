@@ -77,6 +77,7 @@ import collections
 import contextlib
 import contextvars
 import dataclasses
+import itertools
 import math
 import queue
 import threading
@@ -87,6 +88,7 @@ from typing import Any, Callable, Hashable, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.api.autotune import Autotuner
 from repro.api.chunkstore import chunk_stores
@@ -104,12 +106,22 @@ from repro.api.lowering import (
     lower,
     partition_key,
     planned_fold,
+    program_name,
     stable_task_key,
     stacked_fold,
 )
 from repro.api.plan import ExecutionPlan, MapReduceSpec
 from repro.api.policy import Baseline, ExecutionPolicy, Rechunk, SplIter
-from repro.api.profile import ProfileStore
+from repro.api.profile import (
+    SPAN_EXECUTE,
+    SPAN_LOWER,
+    SPAN_MERGE,
+    SPAN_OPERANDS,
+    SPAN_PREPARE,
+    SPAN_SCHEDULE,
+    SPAN_UNIT,
+    ProfileStore,
+)
 from repro.core.blocked import BlockedArray
 from repro.core.engine import EngineReport, TaskEngine
 from repro.core.rechunk import rechunk
@@ -313,10 +325,10 @@ def _merge_partials(
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *partials)
     groups = tuple(members for _, members in plan) if plan else ()
     if len(groups) > 1 and any(len(m) > 1 for m in groups):
-        fold = planned_fold(merge.combine, groups)
-        out = engine.task(fold, key=(merge.key, "fold_plan", groups))(stacked)
+        fold, key = planned_fold(merge.combine, groups), (merge.key, "fold_plan", groups)
     else:
-        out = engine.task(stacked_fold(merge.combine), key=merge.key)(stacked)
+        fold, key = stacked_fold(merge.combine), merge.key
+    out = engine.task(fold, key=key, name=program_name("merge", fold))(stacked)
     engine.current_report.merges += 1
     return out
 
@@ -388,9 +400,15 @@ class _SchedulerState:
       version + 1; first submission: 1).
     """
 
-    def __init__(self, units: list[_Unit], report: EngineReport | None = None):
+    def __init__(
+        self,
+        units: list[_Unit],
+        report: EngineReport | None = None,
+        execute: int = -1,
+    ):
         self.units = units
         self.report = report
+        self.execute = execute  # the execute id its units' spans carry
         self.results: list[Any] = [None] * len(units)
         self.errors: list[BaseException] = []
         self._lock = threading.Lock()
@@ -665,6 +683,7 @@ class _PlanExecutor:
         self._scope_depth = 0
         self._pipeline: collections.deque[_PipelineEntry] = collections.deque()
         self._iteration = 0  # execute_async submit counter (error attribution)
+        self._executes = itertools.count()  # the spans' per-executor execute id
 
     def adopt_shared_assets(self, assets: SharedAssets) -> None:
         """Rebind this executor's caches to server-owned :class:`SharedAssets`.
@@ -724,6 +743,13 @@ class _PlanExecutor:
     # -- the Executor entry point --------------------------------------------
 
     def execute(self, plan: ExecutionPlan) -> ComputeResult:
+        execute = next(self._executes)
+        with TraceAnnotation(
+            SPAN_EXECUTE, execute=execute, mode=plan.spec.policy.mode_name
+        ):
+            return self._execute(plan, execute)
+
+    def _execute(self, plan: ExecutionPlan, execute: int) -> ComputeResult:
         # Barrier rule: a synchronous execute never overlaps — any in-flight
         # pipelined submissions resolve first, in submit order (their
         # futures keep the outcomes; errors surface there, not here).
@@ -749,8 +775,7 @@ class _PlanExecutor:
         # window deltas of the input stores' lifetime counters.
         stores = chunk_stores(spec.inputs)
         store_marks = [(s, s.stats.snapshot()) for s in stores]
-        prepared = self._prepare(spec.inputs, policy, report)
-        graph = lower(spec, prepared.arrays, prepared.groups, self.capabilities)
+        graph = self._prepare_and_lower(spec, policy, report)
         # Per-unit wall profiling (block_until_ready between units) would
         # serialize the async-dispatch pipeline, so it is enabled only for
         # the tuner's probe iterations — the window that needs real
@@ -759,7 +784,7 @@ class _PlanExecutor:
         if tuner is not None and tuner.probing:
             self.profile.sync = True
         try:
-            value = self._schedule(graph)
+            value = self._schedule(graph, execute)
         finally:
             self.profile.sync = sync_prev
         value = jax.block_until_ready(value)
@@ -810,7 +835,9 @@ class _PlanExecutor:
             # contended walls and mistune granularity for every later
             # iteration.  Probes run barriered (depth 1).
             return self._sync_future(plan)
-        return self._submit_entry(spec, policy, tuner)
+        execute = next(self._executes)
+        with TraceAnnotation(SPAN_EXECUTE, execute=execute, mode=spec.policy.mode_name):
+            return self._submit_entry(spec, policy, tuner, execute)
 
     def _sync_future(self, plan: ExecutionPlan) -> ComputeFuture:
         """The non-overlapping fallback: execute now, return a done future."""
@@ -823,7 +850,11 @@ class _PlanExecutor:
         return ComputeFuture.completed(result, iteration=iteration)
 
     def _submit_entry(
-        self, spec: MapReduceSpec, policy: ExecutionPolicy, tuner: Autotuner | None
+        self,
+        spec: MapReduceSpec,
+        policy: ExecutionPolicy,
+        tuner: Autotuner | None,
+        execute: int,
     ) -> ComputeFuture:
         # Flow control: the in-flight window is pipeline_depth whole
         # executes; the oldest entry resolves before a new one is admitted.
@@ -846,9 +877,10 @@ class _PlanExecutor:
         # Prepare/lower/build under the entry's report binding so traces
         # paid at registration time are credited to this submission.
         with self.engine.bind_report(report):
-            prepared = self._prepare(spec.inputs, policy, report)
-            graph = lower(spec, prepared.arrays, prepared.groups, self.capabilities)
-            units, state, merge_unit = self._build_units(graph, report=report)
+            graph = self._prepare_and_lower(spec, policy, report)
+            units, state, merge_unit = self._build_units(
+                graph, report=report, execute=execute
+            )
 
         fut = ComputeFuture(iteration=iteration)
         entry = _PipelineEntry(
@@ -1100,6 +1132,17 @@ class _PlanExecutor:
             if self._pipeline and self._pipeline[0] is entry:
                 self._pipeline.popleft()  # defensive: never spin
 
+    def _prepare_and_lower(
+        self, spec: MapReduceSpec, policy: ExecutionPolicy, report: EngineReport
+    ) -> TaskGraph:
+        """The policy's placement, then the TaskGraph over it (spanned)."""
+        with TraceAnnotation(SPAN_PREPARE):
+            prepared = self._prepare(spec.inputs, policy, report)
+        with TraceAnnotation(SPAN_LOWER) as span:
+            graph = lower(spec, prepared.arrays, prepared.groups, self.capabilities)
+            span.set_metadata(tasks=len(graph.tasks))
+        return graph
+
     def lower(self, plan: ExecutionPlan) -> TaskGraph:
         """Lower a plan for this backend without running it (inspection)."""
         spec = plan.spec
@@ -1296,11 +1339,20 @@ class _PlanExecutor:
     # -- the shared scheduler core ---------------------------------------------
 
     def _bind(self, task: Task) -> Callable[[], Any]:
-        """A nullary thunk running one task through the engine's jit cache."""
+        """A nullary thunk running one task through the engine's jit cache.
+
+        The operands are built under their own span, apart from the launch.
+        """
         if not task.counted:
             return lambda: task.fn(*task.operands())
-        t = self.engine.task(task.fn, key=task.key)
-        return lambda: t(*task.operands())
+        t = self.engine.task(task.fn, key=task.key, name=task.name)
+
+        def run():
+            with TraceAnnotation(SPAN_OPERANDS):
+                operands = task.operands()
+            return t(*operands)
+
+        return run
 
     def _plan_dispatches(self, graph: TaskGraph) -> list[_Unit]:
         """TaskGraph → dispatch units (backend hook; default one per task)."""
@@ -1311,7 +1363,11 @@ class _PlanExecutor:
         ]
 
     def _build_units(
-        self, graph: TaskGraph, *, report: EngineReport | None = None
+        self,
+        graph: TaskGraph,
+        *,
+        report: EngineReport | None = None,
+        execute: int = -1,
     ) -> tuple[list[_Unit], _SchedulerState, _Unit | None]:
         """TaskGraph → ``(units, state, merge_unit)``, merge closure bound.
 
@@ -1371,7 +1427,7 @@ class _PlanExecutor:
                 kind="merge",
             )
             units.append(merge_unit)
-        state = _SchedulerState(units, report=report)
+        state = _SchedulerState(units, report=report, execute=execute)
         state.merge_key = graph.merge.key if graph.merge is not None else None
         if merge_unit is not None:
             for fu in fold_units:
@@ -1405,7 +1461,7 @@ class _PlanExecutor:
         """
         return ()
 
-    def _schedule(self, graph: TaskGraph) -> Any:
+    def _schedule(self, graph: TaskGraph, execute: int) -> Any:
         """Run a TaskGraph through the shared dependency-driven core.
 
         One implementation for every backend: plan dispatch units (hook),
@@ -1414,9 +1470,10 @@ class _PlanExecutor:
         when the graph has a merge, else the per-task partials in plan
         order.
         """
-        units, state, merge_unit = self._build_units(graph)
-        if units:
-            self._drain(state)
+        with TraceAnnotation(SPAN_SCHEDULE):
+            units, state, merge_unit = self._build_units(graph, execute=execute)
+            if units:
+                self._drain(state)
         if state.errors:
             raise state.errors[0]
         if merge_unit is not None:
@@ -1466,24 +1523,33 @@ class _PlanExecutor:
         return self._run_unit_inner(unit, state)
 
     def _run_unit_inner(self, unit: _Unit, state: _SchedulerState) -> list[_Unit]:
-        try:
-            self._acquire_unit(unit)
-            try:
-                t0 = time.perf_counter()
-                value = unit.run()
-                t1 = time.perf_counter()
-                if self.profile.sync:
-                    value = jax.block_until_ready(value)
-                wall = time.perf_counter() - t0
-            finally:
-                self._release_unit(unit)
-            self.profile.record_tasks(
-                unit.tasks,
-                kind=unit.kind,
-                location=unit.location,
-                dispatch_s=t1 - t0,
-                wall_s=wall,
+        if unit.kind in ("merge", "fold"):
+            span = TraceAnnotation(
+                SPAN_MERGE, execute=state.execute, partials=len(unit.deps)
             )
+        else:
+            span = TraceAnnotation(
+                SPAN_UNIT, execute=state.execute, kind=unit.kind, location=unit.location
+            )
+        try:
+            with span:
+                self._acquire_unit(unit)
+                try:
+                    t0 = time.perf_counter()
+                    value = unit.run()
+                    t1 = time.perf_counter()
+                    if self.profile.sync:
+                        value = jax.block_until_ready(value)
+                    wall = time.perf_counter() - t0
+                finally:
+                    self._release_unit(unit)
+                self.profile.record_tasks(
+                    unit.tasks,
+                    kind=unit.kind,
+                    location=unit.location,
+                    dispatch_s=t1 - t0,
+                    wall_s=wall,
+                )
         except BaseException as e:  # noqa: BLE001 — re-raised by _schedule
             state.fail(e)
             return []

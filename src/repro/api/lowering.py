@@ -66,6 +66,7 @@ __all__ = [
     "inputs_signature",
     "partition_key",
     "plan_fingerprint",
+    "program_name",
     "stable_task_key",
     "stacked_fold",
 ]
@@ -117,6 +118,24 @@ def stable_task_key(fn: Callable) -> Hashable:
     except (TypeError, ValueError):  # unhashable default/cell, or empty cell
         return fn
     return ("fn", *parts)
+
+
+def program_name(kind: str, fn: Callable | str) -> str:
+    """``repro_<kind>_<name>``: the name a task's program runs under.
+
+    ``fn`` is the task's function (``functools.partial`` layers peeled) or
+    a kernel's registered name.  A profile then shows the engine's
+    programs as ``jit_repro_…`` whatever wrappers build them, so a trace
+    reduction finds them after a refactor.
+
+    >>> program_name("merge", stacked_fold(lambda a, b: a + b))
+    'repro_merge_fold'
+    """
+    if not isinstance(fn, str):
+        while isinstance(fn, functools.partial):
+            fn = fn.func
+        fn = getattr(fn, "__name__", type(fn).__name__)
+    return f"repro_{kind}_{fn}"
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +352,8 @@ class Task:
     n_data: int = 1
     counted: bool = True
     kernel_name: str | None = None
+    #: name of the task's compiled program (:func:`program_name`)
+    name: str | None = None
     #: ((shape, dtype_str), ...) of the per-task data operands — lets grouped
     #: backends bucket same-signature tasks WITHOUT materializing operands.
     data_shapes: tuple = ()
@@ -784,17 +805,19 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                     task_fn, key, kname = kernel.fn, ("pallas", kernel.key), kernel.name
                 else:
                     task_fn, key, kname = scan_fn, scan_key, None
+                kind = f"partition_{choice}"
                 tasks.append(
                     Task(
                         index=len(tasks),
                         location=g.location,
-                        kind=f"partition_{choice}",
+                        kind=kind,
                         key=key,
                         fn=task_fn,
                         operands=operands,
                         block_ids=ids,
                         n_data=n_in,
                         kernel_name=kname,
+                        name=program_name(kind, kname or spec.fn),
                         chunk_refs=_refs_of(arrays, ids, caps),
                         data_shapes=tuple(
                             (
@@ -827,6 +850,7 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                     operands=operands,
                     block_ids=g.block_ids,
                     n_data=n_in,
+                    name=program_name("partition_materialized", spec.fn),
                     chunk_refs=_refs_of(arrays, g.block_ids, caps),
                     data_shapes=tuple(
                         (
@@ -863,6 +887,7 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                     operands=operands,
                     block_ids=(b,),
                     n_data=n_in,
+                    name=program_name("block", spec.fn),
                     chunk_refs=_refs_of(arrays, (b,), caps),
                     data_shapes=tuple(
                         (a.blocks[b].shape, str(a.blocks[b].dtype)) for a in arrays

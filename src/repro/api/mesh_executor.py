@@ -43,6 +43,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api.executors import _PlanExecutor, _Unit
@@ -53,6 +54,7 @@ from repro.api.lowering import (
     _partition_body,
     stacked_fold,
 )
+from repro.api.profile import SPAN_OPERANDS
 from repro.core.engine import TaskEngine
 
 __all__ = ["MeshExecutor"]
@@ -174,16 +176,17 @@ class MeshExecutor(_PlanExecutor):
         # stack each per-task data operand along a new leading (group) axis
         # and place it split over the mesh; extras are plan-wide, shared by
         # every task of the signature, and replicated
-        per_task = [t.operands() for t in tasks]
-        stacked = tuple(
-            jax.device_put(
-                jnp.stack([ops[j] for ops in per_task], axis=0),
-                NamedSharding(mesh, P(axis)),
+        with TraceAnnotation(SPAN_OPERANDS):
+            per_task = [t.operands() for t in tasks]
+            stacked = tuple(
+                jax.device_put(
+                    jnp.stack([ops[j] for ops in per_task], axis=0),
+                    NamedSharding(mesh, P(axis)),
+                )
+                for j in range(n_data)
             )
-            for j in range(n_data)
-        )
-        extras = jax.device_put(per_task[0][n_data:], NamedSharding(mesh, P()))
-        del per_task
+            extras = jax.device_put(per_task[0][n_data:], NamedSharding(mesh, P()))
+            del per_task
 
         # local fold over the rank's tasks = the generic partition body over
         # the group axis (one source of truth for the first/scan fold)

@@ -31,6 +31,14 @@ What is measured per unit (DESIGN.md §9):
 The store is consumed by :mod:`repro.api.autotune` (per-task overhead
 estimates seed the cost model) and is inspectable by users via
 ``executor.profile.snapshot()``.
+
+Host spans (DESIGN.md §9.1): the scheduler core also opens a
+``jax.profiler.TraceAnnotation`` at each layer boundary, named by the
+``SPAN_*`` constants below.  They are always on (a span costs under a
+microsecond while no profiler runs) and land in the profiler's own trace,
+on the host clock the device events are aligned to, so a trace puts each
+device idle gap on the layer the host was in.  Spans of one execute carry
+its ``execute`` id as a stat.
 """
 
 from __future__ import annotations
@@ -42,7 +50,34 @@ from typing import Any, Hashable, Iterable, Sequence
 
 import numpy as np
 
-__all__ = ["ProfileEvent", "TaskProfile", "ProfileStore", "signature_nbytes"]
+__all__ = [
+    "ProfileEvent",
+    "TaskProfile",
+    "ProfileStore",
+    "signature_nbytes",
+    "SPAN_EXECUTE",
+    "SPAN_PREPARE",
+    "SPAN_LOWER",
+    "SPAN_SCHEDULE",
+    "SPAN_UNIT",
+    "SPAN_OPERANDS",
+    "SPAN_MERGE",
+]
+
+#: one ``execute`` (or pipelined submission); stats ``execute``, ``mode``
+SPAN_EXECUTE = "repro.execute"
+#: the policy's placement (``_prepare``, LRU-cached)
+SPAN_PREPARE = "repro.prepare"
+#: plan -> TaskGraph; stat ``tasks``
+SPAN_LOWER = "repro.lower"
+#: unit building plus the drain of the ready set
+SPAN_SCHEDULE = "repro.schedule"
+#: one task or sharded unit; stats ``execute``, ``kind``, ``location``
+SPAN_UNIT = "repro.unit"
+#: a unit's operand building (the stacking copy), apart from its launch
+SPAN_OPERANDS = "repro.operands"
+#: a merge or fold unit; stats ``execute``, ``partials``
+SPAN_MERGE = "repro.merge"
 
 
 def signature_nbytes(data_shapes: tuple) -> int:

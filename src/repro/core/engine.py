@@ -183,17 +183,27 @@ class TaskEngine:
         finally:
             self._local.report = prev
 
-    def task(self, fn: Callable, *, key: Hashable = None) -> Callable:
-        """Register ``fn`` as a task (jitted once per key, dispatch-counted)."""
+    def task(
+        self, fn: Callable, *, key: Hashable = None, name: str | None = None
+    ) -> Callable:
+        """Register ``fn`` as a task (jitted once per key, dispatch-counted).
+
+        ``name`` names the compiled program (``jit_<name>`` in a profile)
+        without touching ``fn``: the jit wraps a function of that name.  It
+        takes no part in the cache key, so the first registration of a key
+        names its program.  ``dispatch.lower(*args)`` lowers the program
+        without running or counting it.
+        """
         key = key if key is not None else fn
         if key not in self._cache:
-            jfn = jax.jit(fn)
+            jfn = jax.jit(_named(fn, name) if name else fn)
 
             def dispatch(*args, _jfn=jfn, _self=self, **kw):
                 with _self._lock:
                     _self.current_report.dispatches += 1
                 return _jfn(*args, **kw)
 
+            dispatch.lower = jfn.lower
             self._cache[key] = dispatch
             with self._lock:
                 self.traces_total += 1
@@ -206,6 +216,16 @@ class TaskEngine:
                     # credit the newly paid trace to the bound report alone.
                     rep.traces += 1
         return self._cache[key]
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """A function called ``name`` that calls ``fn``: what jit names a program by."""
+
+    def program(*args, **kw):
+        return fn(*args, **kw)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def run_map_reduce(
