@@ -140,9 +140,10 @@ def _resolve_fn(fn_ref: tuple, cache: dict):
 def _build_operands(kind: str, data: tuple, extras: tuple, stores: dict, shm_att):
     """Payloads → operand tuple, mirroring the in-process lowering exactly.
 
-    Stacked kinds (``partition_scan``/``partition_pallas``) stack the
-    blocks on a new leading axis, ``partition_materialized`` concatenates,
-    ``block`` passes the single block through.  Returns the operands plus
+    ``partition_scan`` stacks the blocks on a new leading axis,
+    ``partition_pallas`` passes them as a tuple (the kernel reads each in
+    place), ``partition_materialized`` concatenates, ``block`` passes the
+    single block through.  Returns the operands plus
     the chunk bytes read from spill files (billed upstream as
     ``bytes_loaded`` — shared-memory resolutions move no file bytes and
     bill nothing).
@@ -170,8 +171,10 @@ def _build_operands(kind: str, data: tuple, extras: tuple, stores: dict, shm_att
     ops = []
     for blocks in data:
         arrs = [resolve(b) for b in blocks]
-        if kind in ("partition_scan", "partition_pallas"):
+        if kind == "partition_scan":
             ops.append(jnp.stack(arrs, axis=0))
+        elif kind == "partition_pallas":
+            ops.append(tuple(arrs))
         elif kind == "partition_materialized":
             ops.append(jnp.concatenate(arrs, axis=0))
         else:

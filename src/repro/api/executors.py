@@ -1348,7 +1348,7 @@ class _PlanExecutor:
         t = self.engine.task(task.fn, key=task.key, name=task.name)
 
         def run():
-            with TraceAnnotation(SPAN_OPERANDS):
+            with TraceAnnotation(SPAN_OPERANDS, copied=task.copied):
                 operands = task.operands()
             return t(*operands)
 

@@ -1,12 +1,13 @@
 """Partition-kernel registry — fused Pallas implementations of block fns.
 
 The generic SplIter lowering fuses a partition's per-block work into one
-``lax.scan`` (paper Listing 5).  For block functions with a hand-written
-Pallas partition kernel (``repro.kernels.partition_reduce``) the lowering
-can do strictly better: ONE ``pallas_call`` whose *grid* iterates the
-partition's HBM blocks while the reduction accumulator stays in VMEM —
-the worksharing-task idea of Maroñas et al. (arXiv:2004.03258) expressed
-at the kernel level.
+``lax.scan`` (paper Listing 5) over the stacked blocks.  For block
+functions with a hand-written Pallas partition kernel
+(``repro.kernels.partition_reduce``) the lowering can do strictly better:
+ONE program that walks the partition's HBM blocks where they lie, with no
+stacking copy, while the reduction accumulator stays in VMEM — the
+worksharing-task idea of Maroñas et al. (arXiv:2004.03258) expressed at the
+kernel level.
 
 The registry maps a *base* block function to a factory.  App modules
 register their kernels at import time (``repro/core/apps/histogram.py``,
@@ -14,8 +15,8 @@ register their kernels at import time (``repro/core/apps/histogram.py``,
 ``functools.partial`` layers so e.g. ``partial(histogramdd_block, bins=8)``
 finds the histogram kernel with the right static parameters — and emits a
 ``partition_pallas`` task when the policy's ``fusion`` knob and the backend
-capabilities allow it.  Contract: for a stacked run ``(nblocks, rows, *row)``
-the kernel's result equals folding ``block_fn`` over the blocks with the
+capabilities allow it.  Contract: for a run of same-shape blocks the
+kernel's result equals folding ``block_fn`` over the blocks with the
 plan's ``combine`` (up to float reassociation), so fused and generic
 lowerings are interchangeable.
 """
@@ -59,11 +60,13 @@ class PartitionKernel:
       key: stable jit-cache key — must encode every static parameter baked
         into ``fn`` (e.g. ``("hist_dd", bins, lo, hi)``) so two plans with
         different statics never share a compiled program.
-      fn: ``fn(stacked, *extra_args) -> partial`` where ``stacked`` is the
-        partition's same-shape blocks ``(nblocks, rows, *row_shape)`` and
-        the result matches the block-fn/combine fold over those blocks.
-      supports: optional shape guard ``(stacked_shape, extra_args) -> bool``;
-        returning False falls back to the generic scan lowering.
+      fn: ``fn(blocks, *extra_args) -> partial`` where ``blocks`` is the
+        tuple of a run's same-shape block buffers ``(rows, *row_shape)``,
+        passed as they lie (no stacking copy), and the result matches the
+        block-fn/combine fold over those blocks.
+      supports: optional shape guard ``(stacked_shape, extra_args) -> bool``
+        over the run's shape ``(nblocks, rows, *row_shape)``; returning
+        False falls back to the generic scan lowering.
     """
 
     name: str

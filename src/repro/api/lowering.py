@@ -335,6 +335,8 @@ class Task:
     operands are per-task data, the rest are plan-wide traced extras shared
     by every task of the same ``key`` — the distinction grouped backends
     (MeshExecutor) use to stack data across tasks while replicating extras.
+    A ``partition_pallas`` task's data operand is the tuple of its run's
+    block buffers themselves, uncopied.
 
     ``counted=False`` marks tasks that are *driver* work rather than engine
     dispatches (map_partitions views: the view callback itself dispatches
@@ -356,7 +358,11 @@ class Task:
     name: str | None = None
     #: ((shape, dtype_str), ...) of the per-task data operands — lets grouped
     #: backends bucket same-signature tasks WITHOUT materializing operands.
+    #: A partition's run reads ``(nblocks, rows, *row)`` however it is passed.
     data_shapes: tuple = ()
+    #: block buffers ``operands()`` copies (stacks or concatenates); 0 where
+    #: the data goes in place
+    copied: int = 0
     #: store-held chunk refs this task's operands resolve — populated only
     #: for out-of-core backends (``Capabilities.out_of_core``), which
     #: pin/prefetch/release them around dispatch.
@@ -625,7 +631,7 @@ def _partition_body(block_fn: Callable, combine: Callable, n_in: int) -> Callabl
             p = block_fn(*blk, *extra)
             return combine(acc, p), None
 
-        first = block_fn(*(s[0] for s in data), *extra)
+        first = block_fn(*jax.tree.map(lambda s: s[0], data), *extra)
         acc, _ = jax.lax.scan(body, first, jax.tree.map(lambda s: s[1:], data))
         return acc
 
@@ -796,12 +802,12 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                 stacked_shape = (len(ids), *shape)
                 choice = _pick_fusion(pol, caps, kernel, stacked_shape, extra)
 
-                def operands(ids=ids):
-                    return tuple(
-                        jnp.stack([a.block(b) for b in ids], axis=0) for a in arrays
-                    ) + tuple(resolve_deferred(e) for e in extra)
+                def operands(ids=ids, stack=choice == "scan"):
+                    runs = (tuple(a.block(b) for b in ids) for a in arrays)
+                    data = tuple(jnp.stack(r, axis=0) if stack else r for r in runs)
+                    return data + tuple(resolve_deferred(e) for e in extra)
 
-                if choice == "pallas":
+                if choice == "pallas":  # the kernel reads each block where it lies
                     task_fn, key, kname = kernel.fn, ("pallas", kernel.key), kernel.name
                 else:
                     task_fn, key, kname = scan_fn, scan_key, None
@@ -818,6 +824,7 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                         n_data=n_in,
                         kernel_name=kname,
                         name=program_name(kind, kname or spec.fn),
+                        copied=0 if choice == "pallas" else n_in * len(ids),
                         chunk_refs=_refs_of(arrays, ids, caps),
                         data_shapes=tuple(
                             (
@@ -851,6 +858,7 @@ def _lower_map_blocks(spec, arrays, groups, caps: Capabilities) -> list[Task]:
                     block_ids=g.block_ids,
                     n_data=n_in,
                     name=program_name("partition_materialized", spec.fn),
+                    copied=n_in * len(g.block_ids),
                     chunk_refs=_refs_of(arrays, g.block_ids, caps),
                     data_shapes=tuple(
                         (

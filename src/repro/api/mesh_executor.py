@@ -173,14 +173,18 @@ class MeshExecutor(_PlanExecutor):
         mesh = self._mesh(m)
         axis = self.axis_name
 
-        # stack each per-task data operand along a new leading (group) axis
-        # and place it split over the mesh; extras are plan-wide, shared by
-        # every task of the signature, and replicated
-        with TraceAnnotation(SPAN_OPERANDS):
+        # stack each per-task data operand (each block of a run passed in
+        # place) along a new leading (group) axis and place it split over the
+        # mesh; extras are plan-wide, shared by every task of the signature,
+        # and replicated
+        copied = sum(t.copied + t.n_data * len(t.block_ids) for t in tasks)
+        with TraceAnnotation(SPAN_OPERANDS, copied=copied):
             per_task = [t.operands() for t in tasks]
             stacked = tuple(
                 jax.device_put(
-                    jnp.stack([ops[j] for ops in per_task], axis=0),
+                    jax.tree.map(
+                        lambda *xs: jnp.stack(xs, axis=0), *(ops[j] for ops in per_task)
+                    ),
                     NamedSharding(mesh, P(axis)),
                 )
                 for j in range(n_data)

@@ -74,7 +74,8 @@ SPAN_LOWER = "repro.lower"
 SPAN_SCHEDULE = "repro.schedule"
 #: one task or sharded unit; stats ``execute``, ``kind``, ``location``
 SPAN_UNIT = "repro.unit"
-#: a unit's operand building (the stacking copy), apart from its launch
+#: a unit's operand building, apart from its launch; stat ``copied``, the
+#: blocks it stacks or concatenates (0 where they go in place)
 SPAN_OPERANDS = "repro.operands"
 #: a merge or fold unit; stats ``execute``, ``partials``
 SPAN_MERGE = "repro.merge"

@@ -6,10 +6,10 @@ guaranteed), the final merge is a single reduction task — exactly paper
 Listings 4/5, expressed as one plan on the :mod:`repro.api` layer.
 
 A fused Pallas partition kernel
-(:func:`repro.kernels.partition_reduce.partition_histogramdd`) is
+(:func:`repro.kernels.partition_reduce.partition_histogramdd_blocks`) is
 registered for :func:`histogramdd_block`, so ``SplIter(fusion="pallas")``
-lowers each partition to ONE ``pallas_call`` whose grid iterates the
-partition's blocks with the flat-grid accumulator resident in VMEM.
+lowers each partition to ONE program that walks the partition's blocks
+where they lie, in row tiles, into the flat-grid accumulator.
 
 ``policy=SplIter(partitions_per_location="auto")`` works here too, but the
 autotuner lives on the *executor*: pass a persistent executor across
@@ -32,7 +32,7 @@ from repro.core.engine import EngineReport
 from repro.kernels.partition_reduce import (
     EXACT_COUNT_ROWS,
     histdd_vmem,
-    partition_histogramdd,
+    partition_histogramdd_blocks,
     row_tile,
 )
 
@@ -74,8 +74,8 @@ def _histogram_kernel_factory(args: tuple, kwargs: dict) -> PartitionKernel | No
     return PartitionKernel(
         name="partition_histogramdd",
         key=("hist_dd", bins, lo, hi),
-        fn=lambda stacked: partition_histogramdd(
-            stacked, bins=bins, lo=lo, hi=hi, interpret=pallas_interpret()
+        fn=lambda blocks: partition_histogramdd_blocks(
+            blocks, bins=bins, lo=lo, hi=hi, interpret=pallas_interpret()
         ),
         supports=supports,
     )

@@ -31,7 +31,7 @@ from repro.api.executors import _default_local
 from repro.api.kernels import PartitionKernel, pallas_interpret, register_partition_kernel
 from repro.core.blocked import BlockedArray
 from repro.core.engine import EngineReport
-from repro.kernels.partition_reduce import kmeans_vmem, partition_kmeans, row_tile
+from repro.kernels.partition_reduce import kmeans_vmem, partition_kmeans_blocks, row_tile
 
 __all__ = ["kmeans", "partial_sum_block", "KMeansResult"]
 
@@ -75,8 +75,8 @@ def _kmeans_kernel_factory(args: tuple, kwargs: dict) -> PartitionKernel | None:
     return PartitionKernel(
         name="partition_kmeans",
         key=("kmeans_partial",),
-        fn=lambda stacked, centers: partition_kmeans(
-            stacked, centers, interpret=pallas_interpret()
+        fn=lambda blocks, centers: partition_kmeans_blocks(
+            blocks, centers, interpret=pallas_interpret()
         ),
         supports=_kmeans_supports,
     )

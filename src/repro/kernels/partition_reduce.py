@@ -1,34 +1,37 @@
 """partition_reduce — the paper's ``compute_partition`` at the VMEM level.
 
-The SplIter idea expressed as a TPU kernel (DESIGN.md §2, layer L3): the
-*grid iterates the blocks of a partition* while the reduction accumulator
-stays resident in VMEM; one ``pallas_call`` per partition regardless of how
-many HBM blocks compose it.  Block size (HBM layout granularity) is thereby
+The SplIter idea expressed as a TPU kernel (DESIGN.md §2, layer L3): a
+partition's blocks are walked in order while the reduction accumulator
+stays in VMEM, in one jitted program per partition regardless of how many
+HBM blocks compose it.  Block size (HBM layout granularity) is thereby
 decoupled from kernel-invocation granularity — exactly the paper's
-decoupling, one level down.
+decoupling, one level down.  A partition is a logical group of blocks, as
+in the paper: the blocks are read where they lie, never stacked or copied.
 
 The same decoupling holds one level further down: the fused kernels walk
-each block in *row tiles* (a second grid axis), so VMEM use is set by the
-tile, never by the block's row count.  The tile is sized by reckoning the
-kernel's VMEM bytes per row against :data:`VMEM_BUDGET_BYTES`; a block that
-is not a multiple of the tile has its tail masked in the kernel (the DMA of
-a partial tile never reads past the block).  The kernels read the stacked
-blocks transposed, ``(nblocks, d, rows)``, so rows run along the 128 vector
-lanes and a narrow row (d = 5, d = 20) is not padded out to 128 lanes.
+each block in *row tiles* (a ``pallas_call`` grid per block), so VMEM use
+is set by the tile, never by the block's row count.  The tile is sized by
+reckoning the kernel's VMEM bytes per row against
+:data:`VMEM_BUDGET_BYTES`; a block that is not a multiple of the tile has
+its tail masked in the kernel (the DMA of a partial tile never reads past
+the block).  The kernels read each block ``(rows, d)`` as its ``(d, rows)``
+view, so rows run along the 128 vector lanes and a narrow row (d = 5,
+d = 20) is not padded out to 128 lanes.  On the chip a narrow block is laid
+out ``{0,1:T(8,128)}``, rows minor, so that view is a bitcast.
 
 Three ops, matching the paper's memory-bound applications:
 
 * :func:`partition_histogram` — scatter-free 1-d value histogram: each
   row tile's values are compared against the bin edges (one-hot via two
-  comparisons) and counted with a lane reduction; the (bins,) accumulator
-  never leaves VMEM until the final grid step.  Off the engine's main path
+  comparisons) and counted with a lane reduction into the (bins,)
+  accumulator.  Off the engine's main path
   (``repro.kernels.ops`` only).
 
-* :func:`partition_kmeans` — fused Lloyd partial step: per row tile,
+* :func:`partition_kmeans_blocks` — fused Lloyd partial step: per row tile,
   squared distances to centroids via MXU matmul, hard assignment, one-hot
   matmul accumulation of per-centroid sums and counts in VMEM.
 
-* :func:`partition_histogramdd` — the d-dimensional generalization used by
+* :func:`partition_histogramdd_blocks` — the d-dimensional generalization used by
   the histogram app's fused lowering: rows are digitized per dimension,
   combined into a flat ``bins**d`` cell index, and accumulated scatter-free.
   The cell index is split as ``cell = 128 * high + low``, so the count grid
@@ -37,21 +40,22 @@ Three ops, matching the paper's memory-bound applications:
   per-block ``histogramdd_block`` + sum-combine path (integer counts,
   float32 accumulation is exact below 2**24).
 
-Inputs are the partition's stacked blocks ``(nblocks, rows, d)`` — i.e.
-``Partition.stacked()`` — so the engine can hand a partition straight to
-the kernel.  The execution layer reaches these through the kernel registry
-(``repro.api.kernels``): lowering a ``SplIter(fusion="pallas")`` plan emits
-one such call per same-shape run of a partition.
+The ``*_blocks`` entries take a run's same-shape blocks as a sequence of
+``(rows, d)`` arrays, as they lie; the execution layer reaches them through
+the kernel registry (``repro.api.kernels``): lowering a
+``SplIter(fusion="pallas")`` plan emits one such task per same-shape run of
+a partition.  The stacked entries take ``(nblocks, rows, d)`` — i.e.
+``Partition.stacked()`` — and walk its blocks the same way.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _SUBLANES = 8
@@ -91,42 +95,56 @@ def row_tile(rows: int, row_bytes: int, fixed_bytes: int = 0) -> int | None:
 def _tail_mask(rows: int, tile: int):
     """(1, tile) bool: which lanes of this row tile hold real rows."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-    return pl.program_id(1) * tile + lane < rows
+    return pl.program_id(0) * tile + lane < rows
 
 
-def _tiled_call(kernel, stacked, extras, out_shapes, scratch, *, tile, interpret):
-    """One ``pallas_call`` over a (block, row tile) grid of ``stacked``.
+def _tiled_call(body, blocks, extras, out_shapes, *, tile, interpret):
+    """Walk ``blocks`` in (block, row tile) order, each read where it lies.
 
-    ``stacked`` is ``(nblocks, rows, d)``; the kernel sees ``(d, tile)``
-    slices of its transpose.  ``extras`` and every output are whole arrays
-    resident across the grid (the accumulator pattern).
+    ``blocks`` are same-shape ``(rows, d)`` arrays.  Each goes in as its
+    ``(d, rows)`` view, rows along the lanes: on the chip's layout of a
+    narrow block that view is a bitcast, so no block is copied.  One
+    ``pallas_call`` per block walks its row tiles and calls
+    ``body(x, *extra_refs, *acc_refs)`` with each ``(d, tile)`` tile ``x``;
+    the outputs are the accumulators, resident across the grid, zeroed
+    before the first block and carried from each block's call into the
+    next.  All calls run in the one jitted program of the caller.  (One
+    call copying tiles by hand is not possible: Mosaic refuses a DMA window
+    of d = 20 of a block's 24 padded sublanes, or of a 602-row tail.)
     """
-    nb, rows, d = stacked.shape
-    xt = jnp.swapaxes(stacked, 1, 2)  # (nb, d, rows): rows along lanes
+    rows, d = blocks[0].shape
+    n_ext, n_out = len(extras), len(out_shapes)
+
+    def kernel(x_ref, *refs, first):
+        ext, carried, accs = refs[:n_ext], refs[n_ext:-n_out], refs[-n_out:]
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            for i, acc in enumerate(accs):
+                acc[...] = jnp.zeros_like(acc) if first else carried[i][...]
+
+        body(x_ref[...], *ext, *accs)
 
     def whole(shape):
-        return pl.BlockSpec(shape, lambda b, t: (0,) * len(shape))
+        return pl.BlockSpec(shape, lambda t: (0,) * len(shape))
 
-    return pl.pallas_call(
-        kernel,
-        grid=(nb, pl.cdiv(rows, tile)),
-        in_specs=[pl.BlockSpec((1, d, tile), lambda b, t: (b, 0, t))]
-        + [whole(e.shape) for e in extras],
-        out_specs=[whole(o.shape) for o in out_shapes],
-        out_shape=out_shapes,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(xt, *extras)
+    def call(first):
+        # jitted so that the program lowers each kernel once, not per block
+        return jax.jit(pl.pallas_call(
+            functools.partial(kernel, first=first),
+            grid=(pl.cdiv(rows, tile),),
+            in_specs=[pl.BlockSpec((d, tile), lambda t: (0, t))]
+            + [whole(a.shape) for a in (*extras, *(() if first else out_shapes))],
+            out_specs=[whole(o.shape) for o in out_shapes],
+            out_shape=out_shapes,
+            interpret=interpret,
+        ))
 
-
-def _first_step():
-    return (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
-
-
-def _last_step():
-    return (pl.program_id(0) == pl.num_programs(0) - 1) & (
-        pl.program_id(1) == pl.num_programs(1) - 1
-    )
+    first, rest = call(True), call(False)
+    outs = first(blocks[0].T, *extras)
+    for blk in blocks[1:]:
+        outs = rest(blk.T, *extras, *outs)
+    return outs
 
 
 # ---------------------------------------------------------------------------
@@ -141,12 +159,8 @@ def hist_vmem(bins: int, d: int) -> int:
     return 4 * 2 * d8 + 4 * 4 * b8 + 4 * _SUBLANES * 4
 
 
-def _hist_kernel(x_ref, o_ref, acc, *, bins, lo, hi, rows, tile):
-    @pl.when(_first_step())
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-
-    x = x_ref[0].astype(jnp.float32)            # (d, tile) — one row tile
+def _hist_kernel(x, acc, *, bins, lo, hi, rows, tile):
+    x = x.astype(jnp.float32)                   # (d, tile) — one row tile
     width = (hi - lo) / bins
     # bin membership per value: edges e_j = lo + j*width ; x in bin j  <=>
     # e_j <= x < e_{j+1}, outliers clamped into the edge bins (jnp.clip
@@ -162,10 +176,6 @@ def _hist_kernel(x_ref, o_ref, acc, *, bins, lo, hi, rows, tile):
             hit = hit & _tail_mask(rows, tile)
         acc[...] += jnp.sum(hit.astype(jnp.float32), axis=1, keepdims=True)
 
-    @pl.when(_last_step())
-    def _flush():
-        o_ref[...] = acc[...]
-
 
 @functools.partial(jax.jit, static_argnames=("bins", "lo", "hi", "interpret"))
 def partition_histogram(
@@ -177,16 +187,15 @@ def partition_histogram(
     interpret: bool = True,
 ) -> jax.Array:
     """Per-dimension-flattened value histogram of a whole partition → (bins,)."""
-    nb, rows, d = stacked.shape
+    _, rows, d = stacked.shape
     tile = row_tile(rows, hist_vmem(bins, d))
     if tile is None:
         raise ValueError(f"{bins} bins do not fit the VMEM budget")
     (out,) = _tiled_call(
         functools.partial(_hist_kernel, bins=bins, lo=lo, hi=hi, rows=rows, tile=tile),
-        stacked,
+        tuple(stacked),
         [],
         [jax.ShapeDtypeStruct((bins, 1), jnp.float32)],
-        [pltpu.VMEM((bins, 1), jnp.float32)],
         tile=tile,
         interpret=interpret,
     )
@@ -204,23 +213,20 @@ def histdd_high_cells(bins: int, d: int) -> int:
 
 
 def histdd_vmem(bins: int, d: int) -> tuple[int, int]:
-    """(bytes per tile row, fixed bytes) of :func:`partition_histogramdd`."""
+    """(bytes per tile row, fixed bytes) of :func:`partition_histogramdd_blocks`."""
     h = _pad(histdd_high_cells(bins, d), _SUBLANES)
     d8 = _pad(d, _SUBLANES)
     # double-buffered input tile + digitize temporaries; the two one-hots
     # as compare mask (4 B) + bf16 operand (2 B); flat/high/low row vectors
     per_row = 4 * 2 * d8 + 4 * 2 * d8 + 6 * (h + _LANES) + 4 * _SUBLANES * 4
-    # accumulator + double-buffered output block, both (H, 128) f32
-    fixed = 3 * 4 * h * _LANES
+    # the carried-in and the accumulating output block, (H, 128) f32 each,
+    # both double-buffered
+    fixed = 4 * 4 * h * _LANES
     return per_row, fixed
 
 
-def _histdd_kernel(x_ref, o_ref, acc, *, bins, lo, hi, rows, tile):
-    @pl.when(_first_step())
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-
-    x = x_ref[0].astype(jnp.float32)            # (d, tile) — one row tile
+def _histdd_kernel(x, acc, *, bins, lo, hi, rows, tile):
+    x = x.astype(jnp.float32)                   # (d, tile) — one row tile
     d = x.shape[0]
     # digitize per dimension exactly like histogramdd_block (truncate + clip)
     scaled = (x - lo) / (hi - lo) * bins
@@ -246,9 +252,36 @@ def _histdd_kernel(x_ref, o_ref, acc, *, bins, lo, hi, rows, tile):
         preferred_element_type=jnp.float32,
     )                                            # (H, 128)
 
-    @pl.when(_last_step())
-    def _flush():
-        o_ref[...] = acc[...]
+
+@functools.partial(jax.jit, static_argnames=("bins", "lo", "hi", "interpret"))
+def partition_histogramdd_blocks(
+    blocks: Sequence[jax.Array],  # same-shape (rows, d), read where they lie
+    *,
+    bins: int = 8,
+    lo: float = 0.0,
+    hi: float = 1.0,
+    interpret: bool = True,
+) -> jax.Array:
+    """d-dimensional histogram of a partition's blocks → ``(bins,)*d`` int32.
+
+    Equals ``sum(histogramdd_block(b) for b in blocks)`` bit-exactly — the
+    contract the kernel registry requires for fused/generic interchange.
+    """
+    rows, d = blocks[0].shape
+    high = histdd_high_cells(bins, d)
+    tile = row_tile(rows, *histdd_vmem(bins, d))
+    if tile is None:
+        raise ValueError(f"{bins}**{d} cells do not fit the VMEM budget")
+    (out,) = _tiled_call(
+        functools.partial(_histdd_kernel, bins=bins, lo=lo, hi=hi, rows=rows, tile=tile),
+        blocks,
+        [],
+        [jax.ShapeDtypeStruct((high, _LANES), jnp.float32)],
+        tile=tile,
+        interpret=interpret,
+    )
+    cells = bins**d
+    return out.reshape(-1)[:cells].astype(jnp.int32).reshape((bins,) * d)
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "lo", "hi", "interpret"))
@@ -260,27 +293,10 @@ def partition_histogramdd(
     hi: float = 1.0,
     interpret: bool = True,
 ) -> jax.Array:
-    """d-dimensional histogram of a whole partition → ``(bins,)*d`` int32.
-
-    Equals ``sum(histogramdd_block(b) for b in blocks)`` bit-exactly — the
-    contract the kernel registry requires for fused/generic interchange.
-    """
-    nb, rows, d = stacked.shape
-    high = histdd_high_cells(bins, d)
-    tile = row_tile(rows, *histdd_vmem(bins, d))
-    if tile is None:
-        raise ValueError(f"{bins}**{d} cells do not fit the VMEM budget")
-    (out,) = _tiled_call(
-        functools.partial(_histdd_kernel, bins=bins, lo=lo, hi=hi, rows=rows, tile=tile),
-        stacked,
-        [],
-        [jax.ShapeDtypeStruct((high, _LANES), jnp.float32)],
-        [pltpu.VMEM((high, _LANES), jnp.float32)],
-        tile=tile,
-        interpret=interpret,
+    """:func:`partition_histogramdd_blocks` over a stacked run."""
+    return partition_histogramdd_blocks(
+        tuple(stacked), bins=bins, lo=lo, hi=hi, interpret=interpret
     )
-    cells = bins**d
-    return out.reshape(-1)[:cells].astype(jnp.int32).reshape((bins,) * d)
 
 
 # ---------------------------------------------------------------------------
@@ -289,20 +305,15 @@ def partition_histogramdd(
 
 
 def kmeans_vmem(d: int, k: int) -> int:
-    """Bytes per tile row of :func:`partition_kmeans`."""
+    """Bytes per tile row of :func:`partition_kmeans_blocks`."""
     d8, k8 = _pad(d, _SUBLANES), _pad(k, _SUBLANES)
     # double-buffered input tile + its masked copy; the (k, tile) distance,
     # index, compare and one-hot temporaries; a few (1, tile) row vectors
     return 4 * 3 * d8 + 4 * 6 * k8 + 4 * _SUBLANES * 4
 
 
-def _kmeans_kernel(x_ref, c_ref, sums_ref, counts_ref, acc_s, acc_c, *, rows, tile):
-    @pl.when(_first_step())
-    def _init():
-        acc_s[...] = jnp.zeros_like(acc_s)
-        acc_c[...] = jnp.zeros_like(acc_c)
-
-    x = x_ref[0].astype(jnp.float32)             # (d, tile)
+def _kmeans_kernel(x, c_ref, acc_s, acc_c, *, rows, tile):
+    x = x.astype(jnp.float32)                    # (d, tile)
     c = c_ref[...].astype(jnp.float32)           # (k, d)
     k = c.shape[0]
     if rows % tile:
@@ -329,10 +340,32 @@ def _kmeans_kernel(x_ref, c_ref, sums_ref, counts_ref, acc_s, acc_c, *, rows, ti
     )                                             # (k, d)
     acc_c[...] += jnp.sum(onehot, axis=1, keepdims=True)  # (k, 1)
 
-    @pl.when(_last_step())
-    def _flush():
-        sums_ref[...] = acc_s[...]
-        counts_ref[...] = acc_c[...]
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def partition_kmeans_blocks(
+    blocks: Sequence[jax.Array],  # same-shape (rows, d), read where they lie
+    centers: jax.Array,           # (k, d)
+    *,
+    interpret: bool = True,
+) -> tuple[jax.Array, jax.Array]:
+    """Fused Lloyd partial step over a partition's blocks → (sums (k,d), counts (k,))."""
+    rows, d = blocks[0].shape
+    k = centers.shape[0]
+    tile = row_tile(rows, kmeans_vmem(d, k))
+    if tile is None:
+        raise ValueError(f"k-means rows of d={d}, k={k} do not fit the VMEM budget")
+    sums, counts = _tiled_call(
+        functools.partial(_kmeans_kernel, rows=rows, tile=tile),
+        blocks,
+        [centers],
+        [
+            jax.ShapeDtypeStruct((k, d), jnp.float32),
+            jax.ShapeDtypeStruct((k, 1), jnp.float32),
+        ],
+        tile=tile,
+        interpret=interpret,
+    )
+    return sums, counts[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -342,22 +375,5 @@ def partition_kmeans(
     *,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused Lloyd partial step over a partition → (sums (k,d), counts (k,))."""
-    nb, rows, d = stacked.shape
-    k = centers.shape[0]
-    tile = row_tile(rows, kmeans_vmem(d, k))
-    if tile is None:
-        raise ValueError(f"k-means rows of d={d}, k={k} do not fit the VMEM budget")
-    sums, counts = _tiled_call(
-        functools.partial(_kmeans_kernel, rows=rows, tile=tile),
-        stacked,
-        [centers],
-        [
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k, 1), jnp.float32),
-        ],
-        [pltpu.VMEM((k, d), jnp.float32), pltpu.VMEM((k, 1), jnp.float32)],
-        tile=tile,
-        interpret=interpret,
-    )
-    return sums, counts[:, 0]
+    """:func:`partition_kmeans_blocks` over a stacked run."""
+    return partition_kmeans_blocks(tuple(stacked), centers, interpret=interpret)

@@ -337,6 +337,21 @@ class TestLoweringFusion:
             # C1 bound: <= 2 shape runs per partition + 1 merge
             assert res.report.dispatches <= 2 * 3 + 1
 
+    def test_pallas_tasks_get_their_blocks_in_place(self):
+        """A partition_pallas task's data operand is its run's block buffers
+        themselves, with no stacking copy, while data_shapes still reads the
+        run as (nblocks, rows, d); the partition holding the ragged tail
+        lowers to two same-shape runs."""
+        _, ba = _blocked(97, 12, 3, round_robin_placement)
+        graph = LocalExecutor().lower(_hist_plan(ba, SplIter(fusion="pallas")).plan())
+        assert {t.kind for t in graph.tasks} == {"partition_pallas"}
+        assert len(graph.tasks) == ba.num_locations + 1
+        for t in graph.tasks:
+            (run,) = t.operands()
+            assert all(x is ba.blocks[b] for x, b in zip(run, t.block_ids, strict=True))
+            assert t.data_shapes == (((len(run), *run[0].shape), "float32"),)
+            assert t.copied == 0
+
     def test_pallas_dispatch_counts_match_scan(self):
         _, ba = _blocked(96, 8, 4, round_robin_placement)
         ex = LocalExecutor()

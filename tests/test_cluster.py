@@ -109,6 +109,13 @@ class TestBitIdentity:
         assert identical(h, ref)
         assert rep.remote_dispatches >= 1  # kernel rehydrated by name remotely
 
+    def test_kmeans_pallas_fusion(self, points, cluster):
+        pol = SplIter(partitions_per_location=2, fusion="pallas")
+        ref = kmeans(points, k=4, iters=2, policy=pol)
+        res = kmeans(points, k=4, iters=2, policy=pol, executor=cluster)
+        assert identical(res.centers, ref.centers)
+        assert sum(r.remote_dispatches for r in res.reports) >= 2
+
     def test_kmeans(self, points, cluster):
         ref = kmeans(points, k=4, iters=3, policy=POL)
         res = kmeans(points, k=4, iters=3, policy=POL, executor=cluster)

@@ -213,6 +213,34 @@ class TestPallasFusionApps:
             )
             assert r.total_dispatches <= 5 * (ba.num_locations + 1)  # C1
 
+    def test_kmeans_pallas_answer_is_the_stacked_kernels_fold(self, points):
+        """Each backend's fused answer equals, bit for bit, folding the
+        stacked kernel entry over each task's stacked run in task order:
+        handing the kernel its blocks in place changes no bit."""
+        from repro.api import Collection
+        from repro.core.apps.kmeans import _combine, partial_sum_block
+        from repro.kernels.partition_reduce import partition_kmeans
+
+        _, ba = points
+        centers = jnp.linspace(0.0, 1.0, 4 * 3, dtype=jnp.float32).reshape(4, 3)
+        plan = (
+            Collection.from_blocked(ba)
+            .split(SplIter(fusion="pallas"))
+            .map_blocks(partial_sum_block, extra_args=(centers,))
+            .reduce(_combine)
+        )
+        want = None
+        for t in LocalExecutor().lower(plan.plan()).tasks:
+            run = jnp.stack([ba.blocks[b] for b in t.block_ids])
+            p = partition_kmeans(run, centers)
+            want = p if want is None else _combine(want, p)
+        for ex in (LocalExecutor(), ThreadedExecutor(), MeshExecutor()):
+            got = plan.compute(executor=ex).value
+            for g, w in zip(got, want, strict=True):
+                np.testing.assert_array_equal(
+                    np.asarray(g), np.asarray(w), err_msg=type(ex).__name__
+                )
+
     def test_knn_and_svm_run_on_mesh_executor(self):
         """Apps built on scope()/task()/map_partitions use the fallback
         scheduling path — every plan the other backends accept runs here."""

@@ -21,6 +21,7 @@ from repro.kernels.partition_reduce import (
     MAX_TILE_ROWS,
     partition_histogram,
     partition_kmeans,
+    partition_kmeans_blocks,
 )
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -194,6 +195,35 @@ class TestPartitionReduce:
         h = partition_histogram(st_, bins=8, lo=0.0, hi=1.0)
         r = ref.histogram_ref(st_, bins=8, lo=0.0, hi=1.0)
         np.testing.assert_array_equal(np.asarray(h), np.asarray(r))
+
+    # the block entries read each block where it lies; the stacked entries
+    # are the same walk over a stacked run's blocks
+    @pytest.mark.parametrize("nb,rows,d,k", [
+        (1, 64, 3, 2),                       # one block
+        (3, MAX_TILE_ROWS + 300, 20, 10),    # rows % tile != 0: a masked tail
+        (2, 2 * MAX_TILE_ROWS, 5, 3),        # tiles split the block evenly
+    ])
+    def test_kmeans_block_entry_equals_stacked_bit_for_bit(self, nb, rows, d, k):
+        st_ = randn(nb, rows, d)
+        cen = randn(k, d)
+        blocks = tuple(st_[i] for i in range(nb))
+        for got, want in zip(partition_kmeans_blocks(blocks, cen), partition_kmeans(st_, cen)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("nb,rows,d", [
+        (1, 64, 3), (3, MAX_TILE_ROWS + 300, 3), (2, 2 * MAX_TILE_ROWS, 2),
+    ])
+    def test_histogramdd_block_entry_equals_stacked_bit_for_bit(self, nb, rows, d):
+        from repro.kernels.partition_reduce import (
+            partition_histogramdd,
+            partition_histogramdd_blocks,
+        )
+
+        st_ = jnp.asarray(RNG.uniform(0, 1, (nb, rows, d)).astype(np.float32))
+        got = partition_histogramdd_blocks(tuple(st_[i] for i in range(nb)), bins=8)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(partition_histogramdd(st_, bins=8))
+        )
 
     def test_row_tile_is_bounded_by_vmem_not_block_rows(self):
         from repro.kernels.partition_reduce import (

@@ -2,8 +2,9 @@
 
 Each execute writes ``repro.execute`` ⊃ {``repro.prepare``, ``repro.lower``,
 ``repro.schedule`` ⊃ {``repro.unit`` ⊃ ``repro.operands``, ``repro.merge``}}
-into the profiler's trace (DESIGN.md §9.1), and every task program runs
-under a ``repro_<kind>_<name>`` name.
+into the profiler's trace (DESIGN.md §9.1), ``repro.operands`` counts the
+blocks it copies, and every task program runs under a
+``repro_<kind>_<name>`` name.
 """
 
 from __future__ import annotations
@@ -53,10 +54,10 @@ def _data() -> BlockedArray:
     )
 
 
-def _collection(policy):
+def _collection(policy, data=None):
     centers = jnp.linspace(0.0, 1.0, K * D, dtype=jnp.float32).reshape(K, D)
     return (
-        Collection.from_blocked(_data())
+        Collection.from_blocked(_data() if data is None else data)
         .split(policy)
         .map_blocks(partial_sum_block, extra_args=(centers,))
         .reduce(_combine)
@@ -126,6 +127,33 @@ def test_an_execute_writes_its_spans_nested_with_one_execute_id(tmp_path, policy
         assert {u[3]["kind"] for u in units} == {kind}
         assert {u[3]["location"] for u in units} == set(range(LOCATIONS))
         assert sum(s[0] == SPAN_OPERANDS for s in inside) == n_tasks
+
+
+@pytest.mark.parametrize("policy, copied", [
+    (Baseline(), 0),
+    (SplIter(fusion="scan"), BLOCKS // LOCATIONS),   # the run's blocks, stacked
+    (SplIter(fusion="pallas"), 0),                   # the blocks go in place
+])
+def test_the_operands_span_counts_the_blocks_it_copies(tmp_path, policy, copied):
+    with engine("local") as ex:
+        plan = _collection(policy)
+        ex.execute(plan.plan())  # compile outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            with jax.profiler.TraceAnnotation(WINDOW):
+                jax.block_until_ready(plan.compute(executor=ex).value)
+        n_tasks = len(ex.lower(plan.plan()).tasks)
+
+    spans = _engine_spans(str(tmp_path))
+    assert [s[3]["copied"] for s in spans if s[0] == SPAN_OPERANDS] == [copied] * n_tasks
+
+
+def test_a_pallas_task_gets_its_blocks_in_place():
+    data = _data()
+    with engine("local") as ex:
+        graph = ex.lower(_collection(SplIter(fusion="pallas"), data).plan())
+    for t in graph.tasks:
+        run, _centers = t.operands()
+        assert all(x is data.blocks[b] for x, b in zip(run, t.block_ids, strict=True))
 
 
 @pytest.mark.parametrize("policy, program", [

@@ -15,6 +15,7 @@ collect the same tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,9 @@ import pytest
 from repro.kernels.partition_reduce import (
     partition_histogram,
     partition_histogramdd,
+    partition_histogramdd_blocks,
     partition_kmeans,
+    partition_kmeans_blocks,
 )
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -64,6 +67,27 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _compile_blocks(fn, one_chip, run, *shapes):
+    """Compile ``fn(blocks, *rest)`` for a run ``(nblocks, rows, d)`` of blocks."""
+    nb, rows, d = run
+    block = jax.ShapeDtypeStruct((rows, d), jnp.float32, sharding=one_chip)
+    rest = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in shapes]
+    return jax.jit(fn).lower((block,) * nb, *rest).compile().as_text()
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", re.M)
+
+
+def _block_sized_results(hlo: str, rows: int) -> list[str]:
+    """Opcodes of the instructions that make an array with a block's rows
+    other than a parameter or a bitcast: a copy, a transpose, a fusion."""
+    dims = re.compile(rf"\[(?:\d+,)*{rows}(?:,\d+)*\]")
+    return [
+        op for shape, op in _INSTRUCTION.findall(hlo)
+        if dims.search(shape) and op not in ("parameter", "bitcast")
+    ]
+
+
 @pytest.mark.parametrize(
     "shape,k",
     [
@@ -82,6 +106,23 @@ def test_partition_kmeans_compiles(one_chip, no_persistent_cache, shape, k):
 
 
 @pytest.mark.parametrize(
+    "run,k",
+    [
+        ((16, 156250, 20), 10),  # HiBench large: a kmeans.spliter partition
+        ((16, 204800, 20), 8),   # chip_smoke kmeans partition
+        ((2, 10000, 20), 8),     # masked tail
+    ],
+)
+def test_partition_kmeans_block_entry_reads_blocks_in_place(one_chip, no_persistent_cache, run, k):
+    hlo = _compile_blocks(
+        lambda b, c: partition_kmeans_blocks(b, c, interpret=False),
+        one_chip, run, (k, run[-1]),
+    )
+    assert "tpu_custom_call" in hlo
+    assert _block_sized_results(hlo, run[1]) == []
+
+
+@pytest.mark.parametrize(
     "shape,bins",
     [
         ((16, 32768, 5), 8),     # chip_smoke histogram partition (32,768 cells)
@@ -97,6 +138,17 @@ def test_partition_histogramdd_compiles(one_chip, no_persistent_cache, shape, bi
         one_chip, shape,
     )
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("run", [(16, 32768, 5), (2, 10000, 5), (4, 4096, 6)])
+def test_partition_histogramdd_block_entry_reads_blocks_in_place(
+    one_chip, no_persistent_cache, run
+):
+    hlo = _compile_blocks(
+        lambda b: partition_histogramdd_blocks(b, bins=8, interpret=False), one_chip, run
+    )
+    assert "tpu_custom_call" in hlo
+    assert _block_sized_results(hlo, run[1]) == []
 
 
 def test_partition_histogram_compiles(one_chip, no_persistent_cache):
