@@ -368,6 +368,7 @@ class _Unit:
     origin: Task | None = None
     merge: MergeSpec | None = None
     publish: bool = False
+    ranks: int = 0                 # mesh ranks of a sharded unit
 
 
 class _SchedulerState:
@@ -1528,8 +1529,10 @@ class _PlanExecutor:
                 SPAN_MERGE, execute=state.execute, partials=len(unit.deps)
             )
         else:
+            ranks = {"ranks": unit.ranks} if unit.ranks else {}
             span = TraceAnnotation(
-                SPAN_UNIT, execute=state.execute, kind=unit.kind, location=unit.location
+                SPAN_UNIT, execute=state.execute, kind=unit.kind, location=unit.location,
+                **ranks,
             )
         try:
             with span:

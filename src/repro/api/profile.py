@@ -72,10 +72,13 @@ SPAN_PREPARE = "repro.prepare"
 SPAN_LOWER = "repro.lower"
 #: unit building plus the drain of the ready set
 SPAN_SCHEDULE = "repro.schedule"
-#: one task or sharded unit; stats ``execute``, ``kind``, ``location``
+#: one task or sharded unit; stats ``execute``, ``kind``, ``location``, and
+#: on a sharded unit ``ranks``, the mesh ranks it runs on
 SPAN_UNIT = "repro.unit"
 #: a unit's operand building, apart from its launch; stat ``copied``, the
-#: blocks it stacks or concatenates (0 where they go in place)
+#: blocks it stacks, concatenates or moves to another device (0 where they
+#: go in place), and on a sharded unit ``placed``, the blocks it passes
+#: where they lie
 SPAN_OPERANDS = "repro.operands"
 #: a merge or fold unit; stats ``execute``, ``partials``
 SPAN_MERGE = "repro.merge"

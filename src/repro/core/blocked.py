@@ -14,6 +14,10 @@ Two execution substrates consume this metadata:
   path), so placement queries are exact — the JAX analogue of Dask
   ``who_has`` / dataClay metadata lookups.
 
+On several devices a location is also a device: :func:`location_device`
+names the device of each location, and :meth:`BlockedArray.placed_on` puts
+every block on it, where the mesh backend then reads it in place.
+
 Blocks are dense ``(block_rows, *row_shape)`` arrays.  The *global order* of
 rows (paper §4.1) is ``block_id``-major: row ``r`` of block ``b`` has global
 index ``offset[b] + r``.
@@ -43,6 +47,7 @@ __all__ = [
     "PlacementPolicy",
     "round_robin_placement",
     "contiguous_placement",
+    "location_device",
 ]
 
 # A placement policy maps (num_blocks, num_locations) -> per-block location ids.
@@ -58,6 +63,23 @@ def contiguous_placement(num_blocks: int, num_locations: int) -> np.ndarray:
     """Consecutive runs of blocks per location — models dislib/dataClay fills."""
     per = math.ceil(num_blocks / num_locations)
     return (np.arange(num_blocks, dtype=np.int32) // per).clip(0, num_locations - 1)
+
+
+def location_device(location: int, num_locations: int, devices: Sequence):
+    """The device that holds ``location``'s blocks.
+
+    Of L locations on M devices, location ``l`` goes to device
+    ``l * M // L``: contiguous runs of locations share a device, the
+    grouping in which :class:`~repro.api.mesh_executor.MeshExecutor` deals
+    a location-major task list over its mesh ranks.  Whoever makes or
+    places blocks and the mesh both ask this one function.
+
+    >>> [location_device(l, 8, "ab") for l in range(8)]
+    ['a', 'a', 'a', 'a', 'b', 'b', 'b', 'b']
+    """
+    if not 0 <= location < num_locations:
+        raise ValueError(f"location {location} is not in [0, {num_locations})")
+    return devices[location * len(devices) // num_locations]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,6 +228,19 @@ class BlockedArray:
         """Stack uniform blocks into ``(num_blocks, block_rows, *row_shape)``."""
         assert self.uniform, "stacked() requires uniform block sizes"
         return jnp.stack(list(self.iter_blocks()), axis=0)
+
+    def placed_on(self, devices: Sequence) -> "BlockedArray":
+        """The same blocks, each on the device its location names
+        (:func:`location_device`).
+
+        A block that already lies there is kept as it is; chunk refs are
+        resolved.  The placements and the global order are unchanged.
+        """
+        blocks = tuple(
+            jax.device_put(self.block(i), location_device(int(loc), self.num_locations, devices))
+            for i, loc in enumerate(self.placements)
+        )
+        return BlockedArray(blocks, self.placements, self.num_locations)
 
     def with_placements(self, placements: np.ndarray, num_locations: int) -> "BlockedArray":
         return BlockedArray(self.blocks, np.asarray(placements, np.int32), num_locations)
