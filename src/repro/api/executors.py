@@ -299,12 +299,16 @@ def _merge_partials(
     partials: list[Any],
     plan: tuple[tuple[int, tuple[int, ...]], ...] | None = None,
 ) -> Any:
-    """Single merge task over the stacked partials (paper's @reduction task).
+    """Single merge task over the partials (paper's @reduction task).
 
-    Keyed by the MergeSpec's stable key — NOT the combine object, which apps
-    typically recreate per call — so iterative workloads hit the jit cache.
-    The fold body is the shared :func:`~repro.api.lowering.stacked_fold`
-    (also the MeshExecutor's cross-rank fold — one source of truth).
+    One launch whatever the number of partials: the program takes the
+    list as it stands, stacks it in list order under its own trace, and
+    folds the stack.  Keyed by the MergeSpec's stable key — NOT the
+    combine object, which apps typically recreate per call — so iterative
+    workloads hit the jit cache; jit keys on the list's length and leaf
+    shapes, so each distinct width compiles once.  The fold body is the
+    shared :func:`~repro.api.lowering.stacked_fold` (also the
+    MeshExecutor's cross-rank fold — one source of truth).
 
     ``plan`` is the :func:`~repro.api.lowering.fold_plan` over the partials'
     list positions: when it has more than one group and any group chains,
@@ -322,13 +326,16 @@ def _merge_partials(
     if len(partials) == 1:
         return partials[0]
     engine.current_report.driver_merge_bytes += _tree_nbytes(partials)
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *partials)
     groups = tuple(members for _, members in plan) if plan else ()
     if len(groups) > 1 and any(len(m) > 1 for m in groups):
         fold, key = planned_fold(merge.combine, groups), (merge.key, "fold_plan", groups)
     else:
         fold, key = stacked_fold(merge.combine), merge.key
-    out = engine.task(fold, key=key, name=program_name("merge", fold))(stacked)
+
+    def stack_and_fold(*parts):
+        return fold(jax.tree.map(lambda *xs: jnp.stack(xs, 0), *parts))
+
+    out = engine.task(stack_and_fold, key=key, name=program_name("merge", fold))(*partials)
     engine.current_report.merges += 1
     return out
 

@@ -19,7 +19,7 @@ import pytest
 
 from repro.api import Baseline, Collection, SplIter, engine
 from repro.api.executors import _merge_partials
-from repro.api.lowering import MergeSpec
+from repro.api.lowering import MergeSpec, fold_plan, planned_fold, stacked_fold
 from repro.api.profile import (
     SPAN_EXECUTE,
     SPAN_LOWER,
@@ -175,8 +175,70 @@ def test_the_merge_program_lowers_as_repro_merge_fold():
     partials = [(jnp.ones((K, D)), jnp.ones((K,))) for _ in range(3)]
     _merge_partials(eng, MergeSpec(_combine, key=("merge", "test")), partials)
     (dispatch,) = eng._cache.values()
+    assert "module @jit_repro_merge_fold " in dispatch.lower(*partials).as_text()
+
+
+def _partials(n: int) -> list[tuple[jax.Array, jax.Array]]:
+    """``n`` k-means partials ``(sums (10, 24), counts (10,))``, all distinct."""
+    keys = jax.random.split(jax.random.key(1), n)
+    return [
+        (jax.random.normal(k, (10, 24), jnp.float32),
+         jnp.round(jax.random.uniform(k, (10,), jnp.float32) * 1e5))
+        for k in keys
+    ]
+
+
+def _round_robin_plan(n: int, locations: int = 8) -> tuple:
+    """The fold plan of ``n`` per-block units over round-robin locations."""
+    return fold_plan((i, i % locations) for i in range(n))
+
+
+MERGE_CASES = [
+    pytest.param(2, None, id="flat-2"),
+    pytest.param(8, None, id="flat-8"),
+    pytest.param(128, None, id="flat-128"),
+    pytest.param(128, _round_robin_plan(128), id="planned-8x16"),
+]
+
+
+@pytest.mark.parametrize("n, plan", MERGE_CASES)
+def test_the_merge_is_bit_identical_to_an_eager_stack_then_the_fold(n, plan):
+    partials = _partials(n)
+    out = _merge_partials(
+        TaskEngine(), MergeSpec(_combine, key=("merge", "test")), partials, plan=plan
+    )
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *partials)
-    assert "module @jit_repro_merge_fold " in dispatch.lower(stacked).as_text()
+    if plan is None:
+        fold = stacked_fold(_combine)
+    else:
+        groups = tuple(members for _, members in plan)
+        assert len(groups) == 8 and {len(m) for m in groups} == {16}
+        fold = planned_fold(_combine, groups)
+    want = jax.jit(fold)(stacked)
+    for got, ref in zip(out, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("n, plan", MERGE_CASES)
+def test_a_merge_is_one_launch_that_stacks_under_its_trace(monkeypatch, n, plan):
+    stack, calls = jnp.stack, []
+
+    def recording_stack(arrays, *args, **kw):
+        calls.append(tuple(arrays))
+        return stack(arrays, *args, **kw)
+
+    eng = TaskEngine()
+    eng.new_report("test")
+    partials = _partials(n)
+    before = eng.current_report.dispatches
+    monkeypatch.setattr(jnp, "stack", recording_stack)
+    _merge_partials(eng, MergeSpec(_combine, key=("merge", "test")), partials, plan=plan)
+    monkeypatch.undo()
+    assert eng.current_report.dispatches == before + 1
+    assert eng.current_report.merges == 1
+    assert calls and all(
+        isinstance(x, jax.core.Tracer) for arrays in calls for x in arrays
+    )
 
 
 def test_a_program_name_leaves_the_cache_and_the_counts_as_they_were():
