@@ -603,7 +603,6 @@ class ClusterExecutor(_PlanExecutor):
         # TPU driver must not ship kernels that its CPU workers interpret.
         return dataclasses.replace(
             super().capabilities,
-            name=type(self).__name__,
             prefer_pallas=WORKER_PLATFORM == "tpu",
             remote=True,
             out_of_core=True,

@@ -218,14 +218,10 @@ class Capabilities:
 
     Attributes:
       name: backend label (diagnostics only).
-      pallas_fusion: backend can execute fused Pallas partition kernels;
-        False lowers everything to the generic scan.
       prefer_pallas: under ``fusion="auto"`` pick the Pallas kernel when one
         is registered.  Backends where the kernel runs compiled (TPU) prefer
         it; interpret-mode backends (CPU tests) keep the scan, which is the
         per-backend granularity trade-off of Bora et al. (arXiv:2202.11464).
-      grouped_dispatch: backend consumes location groups as single sharded
-        dispatches (MeshExecutor) rather than per-task calls.
       out_of_core: backend streams chunk-backed blocks under a residency
         budget (StreamExecutor).  Lowering then attaches each task's
         :class:`~repro.api.chunkstore.ChunkRef` operands to the descriptor
@@ -256,9 +252,7 @@ class Capabilities:
     """
 
     name: str = "local"
-    pallas_fusion: bool = True
     prefer_pallas: bool = False
-    grouped_dispatch: bool = False
     out_of_core: bool = False
     remote: bool = False
     pipelined: bool = False
@@ -647,7 +641,7 @@ def _pick_fusion(
 ) -> str:
     """Resolve the SplIter ``fusion`` knob for one same-shape run."""
     mode = getattr(policy, "fusion", "auto")
-    if mode == "scan" or not caps.pallas_fusion:
+    if mode == "scan":
         return "scan"
     if kernel is None or not kernel.supported(stacked_shape, extra_args):
         return "scan"  # automatic fallback: no kernel, or shapes rejected

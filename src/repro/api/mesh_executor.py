@@ -61,7 +61,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api.executors import _PlanExecutor, _Unit
 from repro.api.lowering import (
-    Capabilities,
     Task,
     TaskGraph,
     _partition_body,
@@ -106,14 +105,6 @@ class MeshExecutor(_PlanExecutor):
         self._devices = tuple(devices) if devices is not None else None
         self.axis_name = axis_name
         self._meshes: dict[int, Mesh] = {}
-
-    @property
-    def capabilities(self) -> Capabilities:
-        return Capabilities(
-            name=type(self).__name__,
-            prefer_pallas=jax.default_backend() == "tpu",
-            grouped_dispatch=True,
-        )
 
     # -- mesh plumbing ---------------------------------------------------------
 

@@ -133,9 +133,7 @@ class StreamExecutor(_PlanExecutor):
 
     @property
     def capabilities(self) -> Capabilities:
-        return dataclasses.replace(
-            super().capabilities, name=type(self).__name__, out_of_core=True
-        )
+        return dataclasses.replace(super().capabilities, out_of_core=True)
 
     # -- the Executor entry point (records stores for close()) ---------------
 

@@ -11,7 +11,7 @@ wall-clock: :class:`RechunkStats` counts inter-location traffic (bytes whose
 source and destination locations differ) and the materialized footprint.
 On the mesh substrate the same operation is a resharding ``device_put``,
 whose cost shows up as collective-permute/all-to-all bytes in the lowered
-HLO (see ``repro.analysis.hlo``).
+HLO.
 """
 
 from __future__ import annotations

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels.  partition_reduce holds the engine's fused partition
+# kernels; flash_attention and ssd_scan serve the LM stack, which the engine
+# does not reach; ref.py holds the plain oracles the tests check them with.

@@ -19,19 +19,13 @@ view, so rows run along the 128 vector lanes and a narrow row (d = 5,
 d = 20) is not padded out to 128 lanes.  On the chip a narrow block is laid
 out ``{0,1:T(8,128)}``, rows minor, so that view is a bitcast.
 
-Three ops, matching the paper's memory-bound applications:
-
-* :func:`partition_histogram` — scatter-free 1-d value histogram: each
-  row tile's values are compared against the bin edges (one-hot via two
-  comparisons) and counted with a lane reduction into the (bins,)
-  accumulator.  Off the engine's main path
-  (``repro.kernels.ops`` only).
+Two ops, matching the paper's memory-bound applications:
 
 * :func:`partition_kmeans_blocks` — fused Lloyd partial step: per row tile,
   squared distances to centroids via MXU matmul, hard assignment, one-hot
   matmul accumulation of per-centroid sums and counts in VMEM.
 
-* :func:`partition_histogramdd_blocks` — the d-dimensional generalization used by
+* :func:`partition_histogramdd_blocks` — the d-dimensional histogram of
   the histogram app's fused lowering: rows are digitized per dimension,
   combined into a flat ``bins**d`` cell index, and accumulated scatter-free.
   The cell index is split as ``cell = 128 * high + low``, so the count grid
@@ -40,12 +34,14 @@ Three ops, matching the paper's memory-bound applications:
   per-block ``histogramdd_block`` + sum-combine path (integer counts,
   float32 accumulation is exact below 2**24).
 
-The ``*_blocks`` entries take a run's same-shape blocks as a sequence of
-``(rows, d)`` arrays, as they lie; the execution layer reaches them through
-the kernel registry (``repro.api.kernels``): lowering a
-``SplIter(fusion="pallas")`` plan emits one such task per same-shape run of
-a partition.  The stacked entries take ``(nblocks, rows, d)`` — i.e.
-``Partition.stacked()`` — and walk its blocks the same way.
+Each takes a run's same-shape blocks as a sequence of ``(rows, d)``
+arrays, as they lie; the execution layer reaches them through the kernel
+registry (``repro.api.kernels``): lowering a ``SplIter(fusion="pallas")``
+plan emits one such task per same-shape run of a partition.
+
+:func:`partition_histogram`, a scatter-free 1-d value histogram over a
+stacked ``(nblocks, rows, d)`` run, is off the engine's path: no app
+registers it.
 """
 
 from __future__ import annotations
@@ -284,21 +280,6 @@ def partition_histogramdd_blocks(
     return out.reshape(-1)[:cells].astype(jnp.int32).reshape((bins,) * d)
 
 
-@functools.partial(jax.jit, static_argnames=("bins", "lo", "hi", "interpret"))
-def partition_histogramdd(
-    stacked: jax.Array,  # (nblocks, rows, d)
-    *,
-    bins: int = 8,
-    lo: float = 0.0,
-    hi: float = 1.0,
-    interpret: bool = True,
-) -> jax.Array:
-    """:func:`partition_histogramdd_blocks` over a stacked run."""
-    return partition_histogramdd_blocks(
-        tuple(stacked), bins=bins, lo=lo, hi=hi, interpret=interpret
-    )
-
-
 # ---------------------------------------------------------------------------
 # k-means partial step
 # ---------------------------------------------------------------------------
@@ -366,14 +347,3 @@ def partition_kmeans_blocks(
         interpret=interpret,
     )
     return sums, counts[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def partition_kmeans(
-    stacked: jax.Array,   # (nblocks, rows, d)
-    centers: jax.Array,   # (k, d)
-    *,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """:func:`partition_kmeans_blocks` over a stacked run."""
-    return partition_kmeans_blocks(tuple(stacked), centers, interpret=interpret)

@@ -215,11 +215,11 @@ class TestPallasFusionApps:
 
     def test_kmeans_pallas_answer_is_the_stacked_kernels_fold(self, points):
         """Each backend's fused answer equals, bit for bit, folding the
-        stacked kernel entry over each task's stacked run in task order:
+        kernel's block entry over each task's stacked run in task order:
         handing the kernel its blocks in place changes no bit."""
         from repro.api import Collection
         from repro.core.apps.kmeans import _combine, partial_sum_block
-        from repro.kernels.partition_reduce import partition_kmeans
+        from repro.kernels.partition_reduce import partition_kmeans_blocks
 
         _, ba = points
         centers = jnp.linspace(0.0, 1.0, 4 * 3, dtype=jnp.float32).reshape(4, 3)
@@ -232,7 +232,7 @@ class TestPallasFusionApps:
         want = None
         for t in LocalExecutor().lower(plan.plan()).tasks:
             run = jnp.stack([ba.blocks[b] for b in t.block_ids])
-            p = partition_kmeans(run, centers)
+            p = partition_kmeans_blocks(tuple(run), centers)
             want = p if want is None else _combine(want, p)
         for ex in (LocalExecutor(), ThreadedExecutor(), MeshExecutor()):
             got = plan.compute(executor=ex).value

@@ -77,6 +77,15 @@ def _cluster(**kw) -> ClusterExecutor:
     return ClusterExecutor(**kw)
 
 
+def _segment_prefix(ex: ClusterExecutor) -> str | None:
+    """The executor's own shm segment prefix, or None without a data plane.
+
+    Leak checks are scoped to it: pools of other test files running at the
+    same time keep their segments live under the global prefix.
+    """
+    return ex._shm.prefix if ex._shm is not None else None
+
+
 def _blocked(a, block_rows=256, locs=2) -> BlockedArray:
     return BlockedArray.from_array(
         jnp.asarray(a), block_rows, num_locations=locs, policy=round_robin_placement
@@ -148,6 +157,7 @@ def test_chaos_rounds_bit_identical_with_exact_accounting(points, seed):
     cs = ChaosSchedule(seed=seed, rounds=3)
     ref, _ = histogram(points, bins=8, policy=POL)
     ex = _cluster(fault_plan=cs.fault_plan(), steal=True, max_workers=8)
+    prefix = _segment_prefix(ex)
     applied = 0
     reports = []
     try:
@@ -167,7 +177,7 @@ def test_chaos_rounds_bit_identical_with_exact_accounting(points, seed):
             assert len(ex.retry_log) >= 1  # the kill really fired
     finally:
         ex.close()
-    assert leaked_segments() == []
+    assert prefix is None or leaked_segments(prefix) == []
 
 
 # ---------------------------------------------------------------------------
@@ -178,6 +188,7 @@ def test_chaos_rounds_bit_identical_with_exact_accounting(points, seed):
 def test_straggler_triggers_steals_bit_identical(points):
     ref = kmeans(points, k=4, iters=3, policy=POL)
     ex = _cluster(fault_plan=FaultPlan(slow=((0, 0.05),)), steal=True)
+    prefix = _segment_prefix(ex)
     try:
         res = kmeans(points, k=4, iters=3, policy=POL, executor=ex)
         steals = sum(r.steals for r in res.reports)
@@ -193,7 +204,7 @@ def test_straggler_triggers_steals_bit_identical(points):
             assert ex._shm.pinned_segments() == {}
     finally:
         ex.close()
-    assert leaked_segments() == []
+    assert prefix is None or leaked_segments(prefix) == []
 
 
 def test_steal_disabled_by_default(points):
@@ -229,6 +240,7 @@ def test_midrun_preemption_is_bit_identical_and_free_of_retries():
     ref = plan().compute(executor=LocalExecutor())
     # worker 0 is slowed so its queue is provably non-empty at shrink time
     ex = _cluster(fault_plan=FaultPlan(slow=((0, 0.1),)))
+    prefix = _segment_prefix(ex)
     try:
         fut = plan().compute_async(executor=ex)
         assert ex.shrink(0) == 0  # preempt the busy owner mid-run
@@ -239,12 +251,13 @@ def test_midrun_preemption_is_bit_identical_and_free_of_retries():
         assert ex.scale_log == [{"event": "shrink", "worker": 0}]
     finally:
         ex.close()
-    assert leaked_segments() == []
+    assert prefix is None or leaked_segments(prefix) == []
 
 
 def test_grow_shrink_between_runs(points):
     ref, _ = histogram(points, bins=8, policy=POL)
     ex = _cluster(steal=True, max_workers=8)
+    prefix = _segment_prefix(ex)
     try:
         h0, _ = histogram(points, bins=8, policy=POL, executor=ex)
         wid = ex.grow()
@@ -258,7 +271,7 @@ def test_grow_shrink_between_runs(points):
         assert [e["event"] for e in ex.scale_log] == ["grow", "shrink"]
     finally:
         ex.close()
-    assert leaked_segments() == []
+    assert prefix is None or leaked_segments(prefix) == []
 
 
 def test_grow_respects_max_workers():
@@ -274,6 +287,7 @@ def test_grow_respects_max_workers():
 def test_autoscaler_grows_under_backlog(points):
     ref, _ = histogram(points, bins=8, policy=POL)
     ex = _cluster(autoscale=True, scale_up_backlog=1, max_workers=6)
+    prefix = _segment_prefix(ex)
     try:
         reports = []
         for _ in range(2):
@@ -286,7 +300,7 @@ def test_autoscaler_grows_under_backlog(points):
         assert sum(r.retries for r in reports) == 0
     finally:
         ex.close()
-    assert leaked_segments() == []
+    assert prefix is None or leaked_segments(prefix) == []
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ All kernels run in interpret=True (Pallas interpreter on CPU); the same
 kernel bodies compile to Mosaic on TPU.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,7 +21,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.partition_reduce import (
     MAX_TILE_ROWS,
     partition_histogram,
-    partition_kmeans,
+    partition_histogramdd_blocks,
     partition_kmeans_blocks,
 )
 from repro.kernels.ssd_scan import ssd_scan
@@ -100,7 +101,7 @@ class TestPartitionReduce:
     def test_kmeans_shapes(self, nb, rows, d, k):
         st_ = randn(nb, rows, d)
         cen = randn(k, d)
-        sums, counts = partition_kmeans(st_, cen)
+        sums, counts = partition_kmeans_blocks(tuple(st_), cen)
         rs, rc = ref.kmeans_ref(st_, cen)
         np.testing.assert_allclose(np.asarray(sums), np.asarray(rs), rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(counts), np.asarray(rc))
@@ -109,13 +110,12 @@ class TestPartitionReduce:
         (1, 16, 1, 8), (4, 32, 2, 4), (3, 8, 3, 4), (2, 64, 1, 128),
     ])
     def test_histogramdd_matches_block_fn(self, nb, rows, d, bins):
-        """The fused-kernel contract: partition_histogramdd == folding the
-        app's histogramdd_block over the stacked blocks with + (bit-exact)."""
+        """The fused-kernel contract: partition_histogramdd_blocks == folding
+        the app's histogramdd_block over the blocks with + (bit-exact)."""
         from repro.core.apps.histogram import histogramdd_block
-        from repro.kernels.partition_reduce import partition_histogramdd
 
         st_ = jnp.asarray(RNG.uniform(0, 1, (nb, rows, d)).astype(np.float32))
-        h = partition_histogramdd(st_, bins=bins, lo=0.0, hi=1.0)
+        h = partition_histogramdd_blocks(tuple(st_), bins=bins, lo=0.0, hi=1.0)
         want = sum(
             histogramdd_block(st_[i], bins=bins, lo=0.0, hi=1.0) for i in range(nb)
         )
@@ -125,21 +125,18 @@ class TestPartitionReduce:
 
     def test_histogramdd_outliers_clamped(self):
         from repro.core.apps.histogram import histogramdd_block
-        from repro.kernels.partition_reduce import partition_histogramdd
 
         st_ = jnp.asarray(RNG.normal(0.5, 2.0, (3, 16, 2)).astype(np.float32))
-        h = partition_histogramdd(st_, bins=4, lo=0.0, hi=1.0)
+        h = partition_histogramdd_blocks(tuple(st_), bins=4, lo=0.0, hi=1.0)
         want = sum(histogramdd_block(st_[i], bins=4, lo=0.0, hi=1.0) for i in range(3))
         np.testing.assert_array_equal(np.asarray(h), np.asarray(want))
 
     def test_histogramdd_block_count_invariance(self):
         """Same data, different block counts → identical flat grid (the
         kernel-level granularity-decoupling claim, d-dimensional)."""
-        from repro.kernels.partition_reduce import partition_histogramdd
-
         x = jnp.asarray(RNG.uniform(0, 1, (64, 2)).astype(np.float32))
         outs = [
-            partition_histogramdd(x.reshape(nb, -1, 2), bins=4, lo=0.0, hi=1.0)
+            partition_histogramdd_blocks(tuple(x.reshape(nb, -1, 2)), bins=4, lo=0.0, hi=1.0)
             for nb in (1, 2, 4, 8)
         ]
         for h in outs[1:]:
@@ -153,7 +150,7 @@ class TestPartitionReduce:
         outs = []
         for nb in (1, 2, 4, 8):
             st_ = x.reshape(nb, -1, 4)
-            outs.append(partition_kmeans(st_, cen))
+            outs.append(partition_kmeans_blocks(tuple(st_), cen))
         for s, c in outs[1:]:
             np.testing.assert_allclose(
                 np.asarray(s), np.asarray(outs[0][0]), rtol=1e-5, atol=1e-5
@@ -169,7 +166,7 @@ class TestPartitionReduce:
         """No row of a partial last tile's padding reaches a sum or a count."""
         st_ = randn(nb, rows, d)
         cen = randn(k, d)
-        sums, counts = partition_kmeans(st_, cen)
+        sums, counts = partition_kmeans_blocks(tuple(st_), cen)
         rs, rc = ref.kmeans_ref(st_, cen)
         np.testing.assert_allclose(np.asarray(sums), np.asarray(rs), rtol=1e-5, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(counts), np.asarray(rc))
@@ -179,10 +176,9 @@ class TestPartitionReduce:
     ])
     def test_histogramdd_row_tiles_mask_the_tail(self, nb, rows, d):
         from repro.core.apps.histogram import histogramdd_block
-        from repro.kernels.partition_reduce import partition_histogramdd
 
         st_ = jnp.asarray(RNG.normal(0.5, 0.7, (nb, rows, d)).astype(np.float32))
-        h = partition_histogramdd(st_, bins=8, lo=0.0, hi=1.0)
+        h = partition_histogramdd_blocks(tuple(st_), bins=8, lo=0.0, hi=1.0)
         want = sum(
             histogramdd_block(st_[i], bins=8, lo=0.0, hi=1.0) for i in range(nb)
         )
@@ -196,8 +192,8 @@ class TestPartitionReduce:
         r = ref.histogram_ref(st_, bins=8, lo=0.0, hi=1.0)
         np.testing.assert_array_equal(np.asarray(h), np.asarray(r))
 
-    # the block entries read each block where it lies; the stacked entries
-    # are the same walk over a stacked run's blocks
+    # the block entries read each block where it lies: separately allocated
+    # buffers and slices of one stacked buffer give the same bits
     @pytest.mark.parametrize("nb,rows,d,k", [
         (1, 64, 3, 2),                       # one block
         (3, MAX_TILE_ROWS + 300, 20, 10),    # rows % tile != 0: a masked tail
@@ -206,24 +202,20 @@ class TestPartitionReduce:
     def test_kmeans_block_entry_equals_stacked_bit_for_bit(self, nb, rows, d, k):
         st_ = randn(nb, rows, d)
         cen = randn(k, d)
-        blocks = tuple(st_[i] for i in range(nb))
-        for got, want in zip(partition_kmeans_blocks(blocks, cen), partition_kmeans(st_, cen)):
+        blocks = tuple(jnp.asarray(np.asarray(st_[i])) for i in range(nb))
+        sliced = jax.jit(lambda s, c: partition_kmeans_blocks(tuple(s), c))(st_, cen)
+        for got, want in zip(partition_kmeans_blocks(blocks, cen), sliced):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @pytest.mark.parametrize("nb,rows,d", [
         (1, 64, 3), (3, MAX_TILE_ROWS + 300, 3), (2, 2 * MAX_TILE_ROWS, 2),
     ])
     def test_histogramdd_block_entry_equals_stacked_bit_for_bit(self, nb, rows, d):
-        from repro.kernels.partition_reduce import (
-            partition_histogramdd,
-            partition_histogramdd_blocks,
-        )
-
         st_ = jnp.asarray(RNG.uniform(0, 1, (nb, rows, d)).astype(np.float32))
-        got = partition_histogramdd_blocks(tuple(st_[i] for i in range(nb)), bins=8)
-        np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(partition_histogramdd(st_, bins=8))
-        )
+        blocks = tuple(jnp.asarray(np.asarray(st_[i])) for i in range(nb))
+        got = partition_histogramdd_blocks(blocks, bins=8)
+        sliced = jax.jit(lambda s: partition_histogramdd_blocks(tuple(s), bins=8))(st_)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
 
     def test_row_tile_is_bounded_by_vmem_not_block_rows(self):
         from repro.kernels.partition_reduce import (

@@ -4,8 +4,9 @@ Nothing runs: each test compiles one kernel for a *described* v5e (the TPU
 compiler is installed even where no chip is attached) and checks that the
 Mosaic kernel is in the program.  This is what the interpreter cannot
 show: a kernel that asks for more VMEM than the chip has, or that uses an
-op Mosaic refuses, fails here.  Shapes are the per-partition blocks of
-``chip_smoke.py`` and of the benches' ``--full`` sizes.
+op Mosaic refuses, fails here.  Shapes are the per-partition runs of the
+cells in ``chipbench/`` (HiBench k-means large and gigantic), with the
+benches' ``--full`` sizes and masked tails beside them.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU runtime, and the test workers must all
@@ -24,9 +25,7 @@ import pytest
 
 from repro.kernels.partition_reduce import (
     partition_histogram,
-    partition_histogramdd,
     partition_histogramdd_blocks,
-    partition_kmeans,
     partition_kmeans_blocks,
 )
 
@@ -75,11 +74,19 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _compile_blocks(fn, one_chip, run, *shapes):
-    """Compile ``fn(blocks, *rest)`` for a run ``(nblocks, rows, d)`` of blocks."""
+def _compile_blocks(fn, one_chip, form, run, *shapes):
+    """Compile ``fn(blocks, *rest)`` for a run ``(nblocks, rows, d)`` of blocks.
+
+    ``form`` "blocks" passes separately allocated buffers, as the engine
+    does; "stacked" passes slices of one ``(nblocks, rows, d)`` buffer.
+    """
     nb, rows, d = run
-    block = jax.ShapeDtypeStruct((rows, d), jnp.float32, sharding=one_chip)
     rest = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in shapes]
+    if form == "stacked":
+        stacked = jax.ShapeDtypeStruct(run, jnp.float32, sharding=one_chip)
+        program = jax.jit(lambda s, *r: fn(tuple(s), *r))
+        return program.lower(stacked, *rest).compile().as_text()
+    block = jax.ShapeDtypeStruct((rows, d), jnp.float32, sharding=one_chip)
     return jax.jit(fn).lower((block,) * nb, *rest).compile().as_text()
 
 
@@ -96,67 +103,51 @@ def _block_sized_results(hlo: str, rows: int) -> list[str]:
     ]
 
 
+# Each run compiles as separate buffers, where the kernel must read every
+# block in place, or as slices of one stacked buffer.
 @pytest.mark.parametrize(
-    "shape,k",
+    "form,run,k",
     [
-        ((16, 204800, 20), 8),   # chip_smoke kmeans partition
-        ((16, 4096, 20), 8),     # bench_kmeans --full, 16 blocks per location
-        ((1, 65536, 20), 8),     # bench_kmeans --full, 1 block per location
-        ((2, 10000, 20), 8),     # rows not a multiple of the tile: masked tail
+        ("blocks", (16, 156250, 20), 10),  # HiBench large: a kmeans.spliter partition
+        ("blocks", (16, 204800, 20), 8),   # chip_smoke kmeans partition
+        ("blocks", (2, 10000, 20), 8),     # masked tail
+        ("stacked", (16, 204800, 20), 8),
+        ("stacked", (16, 4096, 20), 8),    # bench_kmeans --full, 16 blocks per location
+        ("stacked", (1, 65536, 20), 8),    # bench_kmeans --full, 1 block per location
+        ("stacked", (2, 10000, 20), 8),    # rows not a multiple of the tile
     ],
 )
-def test_partition_kmeans_compiles(one_chip, no_persistent_cache, shape, k):
-    hlo = _compile(
-        lambda s, c: partition_kmeans(s, c, interpret=False),
-        one_chip, shape, (k, shape[-1]),
-    )
-    assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize(
-    "run,k",
-    [
-        ((16, 156250, 20), 10),  # HiBench large: a kmeans.spliter partition
-        ((16, 204800, 20), 8),   # chip_smoke kmeans partition
-        ((2, 10000, 20), 8),     # masked tail
-    ],
-)
-def test_partition_kmeans_block_entry_reads_blocks_in_place(one_chip, no_persistent_cache, run, k):
+def test_partition_kmeans_compiles(one_chip, no_persistent_cache, form, run, k):
     hlo = _compile_blocks(
         lambda b, c: partition_kmeans_blocks(b, c, interpret=False),
-        one_chip, run, (k, run[-1]),
+        one_chip, form, run, (k, run[-1]),
     )
     assert "tpu_custom_call" in hlo
-    assert _block_sized_results(hlo, run[1]) == []
+    if form == "blocks":
+        assert _block_sized_results(hlo, run[1]) == []
 
 
 @pytest.mark.parametrize(
-    "shape,bins",
+    "form,run",
     [
-        ((16, 32768, 5), 8),     # chip_smoke histogram partition (32,768 cells)
-        ((16, 8192, 5), 8),      # bench_histogram --full, 16 blocks per location
-        ((1, 131072, 5), 8),     # bench_histogram --full, 1 block per location
-        ((2, 10000, 5), 8),      # masked tail
-        ((4, 4096, 6), 8),       # the most cells the app's guard admits at bins=8
+        ("blocks", (16, 32768, 5)),   # chip_smoke histogram partition (32,768 cells)
+        ("blocks", (2, 10000, 5)),    # masked tail
+        ("blocks", (4, 4096, 6)),     # the most cells the app's guard admits at bins=8
+        ("stacked", (16, 32768, 5)),
+        ("stacked", (16, 8192, 5)),   # bench_histogram --full, 16 blocks per location
+        ("stacked", (1, 131072, 5)),  # bench_histogram --full, 1 block per location
+        ("stacked", (2, 10000, 5)),
+        ("stacked", (4, 4096, 6)),
     ],
 )
-def test_partition_histogramdd_compiles(one_chip, no_persistent_cache, shape, bins):
-    hlo = _compile(
-        lambda s: partition_histogramdd(s, bins=bins, interpret=False),
-        one_chip, shape,
-    )
-    assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize("run", [(16, 32768, 5), (2, 10000, 5), (4, 4096, 6)])
-def test_partition_histogramdd_block_entry_reads_blocks_in_place(
-    one_chip, no_persistent_cache, run
-):
+def test_partition_histogramdd_compiles(one_chip, no_persistent_cache, form, run):
     hlo = _compile_blocks(
-        lambda b: partition_histogramdd_blocks(b, bins=8, interpret=False), one_chip, run
+        lambda b: partition_histogramdd_blocks(b, bins=8, interpret=False),
+        one_chip, form, run,
     )
     assert "tpu_custom_call" in hlo
-    assert _block_sized_results(hlo, run[1]) == []
+    if form == "blocks":
+        assert _block_sized_results(hlo, run[1]) == []
 
 
 def test_partition_histogram_compiles(one_chip, no_persistent_cache):
